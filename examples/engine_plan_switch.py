@@ -28,7 +28,7 @@ from repro.core.schedule import make_plan
 from repro.data import SyntheticTextDataset
 from repro.models.common import ModelConfig
 from repro.optim import make_optimizer
-from repro.pipeline.engine import make_pipeline_step
+from repro.pipeline.engine import make_pipeline_step, stage_mesh
 from repro.pipeline.stage import StagedModel
 from repro.training import TrainState, create_train_state
 
@@ -45,7 +45,7 @@ staged = StagedModel.build(cfg, S)
 params = staged.init_all_stages(jax.random.PRNGKey(0))
 opt = make_optimizer("adamw", schedule=lambda s: jnp.float32(2e-3))
 state = create_train_state(params, opt)
-mesh = jax.make_mesh((S,), ("stage",))
+mesh = stage_mesh(S)
 
 # ALL candidate plans compiled up front (the Ada-Grouper scheduler keeps
 # every task graph alive, §3.2.1)

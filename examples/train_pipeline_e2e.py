@@ -24,7 +24,7 @@ from repro.core import ScheduleSpec
 from repro.core.schedule import make_plan
 from repro.data import SyntheticTextDataset
 from repro.optim import linear_warmup_cosine, make_optimizer
-from repro.pipeline.engine import make_pipeline_step
+from repro.pipeline.engine import make_pipeline_step, stage_mesh
 from repro.pipeline.stage import StagedModel
 from repro.training import TrainState, create_train_state
 
@@ -64,7 +64,7 @@ def main():
 
     opt = make_optimizer("adamw", linear_warmup_cosine(3e-3, 20, args.steps))
     state = create_train_state(params, opt)
-    mesh = jax.make_mesh((S,), ("stage",))
+    mesh = stage_mesh(S)
     engine = make_pipeline_step(staged, make_plan(S, M, spec=ScheduleSpec(k=k)), mesh)
 
     @jax.jit

@@ -46,7 +46,7 @@ from repro.core.kinds import ScheduleSpec
 from repro.core.schedule import make_plan, tick_table, tick_table_stats
 from repro.launch.hlo_analysis import analyze_hlo, roofline_terms
 from repro.models.common import param_count
-from repro.pipeline.engine import make_pipeline_step
+from repro.pipeline.engine import make_pipeline_step, stage_mesh
 from repro.pipeline.stage import StagedModel
 
 ARTIFACT_DIR = os.path.join(
@@ -203,7 +203,7 @@ def run(config: str, S: int, M: int, k: int, batch: int, seq: int, out_dir: str)
     staged = StagedModel.build(cfg, S)
     plan = make_plan(S, M, k)
     stats = tick_table_stats(tick_table(plan))
-    mesh = jax.make_mesh((S, jax.device_count() // S), ("stage", "data"))
+    mesh = stage_mesh(S, jax.device_count() // S)
     b_mb = batch // M
     print(f"{config}: {cfg.num_layers}L over {S} stages x {mesh.shape['data']} DP, "
           f"{plan.name}, ticks={stats['ticks']:.0f} "

@@ -59,7 +59,7 @@ from repro.core.devicespec import (
 from repro.launch.train_adaptive import fig10_parts
 from repro.models.common import ModelConfig
 from repro.obs import Observability
-from repro.runtime import PassiveLinkFeed, TelemetryBus
+from repro.runtime import PassiveLinkFeed, TelemetryBus, enable_persistent_cache
 from repro.serve import ArrivalProcess, ServeRuntime, SLOTracker, make_slo_objective
 
 ARTIFACT_DIR = os.path.join(
@@ -281,6 +281,7 @@ def main(argv=None) -> int:
         "request lanes, tick lane, tuner decisions)",
     )
     args = ap.parse_args(argv)
+    enable_persistent_cache()
     if os.environ.get("REPRO_SMOKE"):
         args.requests = min(args.requests, 24)
 

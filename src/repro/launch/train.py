@@ -29,6 +29,7 @@ from repro.configs import get_arch
 from repro.data import SyntheticTextDataset
 from repro.models import api
 from repro.optim import linear_warmup_cosine, make_optimizer
+from repro.runtime import enable_persistent_cache
 from repro.training import create_train_state, make_train_step
 
 
@@ -101,7 +102,7 @@ def run_spmd(args):
 def run_pipeline(args):
     from repro.configs.gpt import GPT_CONFIGS
     from repro.core.schedule import make_plan
-    from repro.pipeline.engine import make_pipeline_step
+    from repro.pipeline.engine import make_pipeline_step, stage_mesh
     from repro.pipeline.stage import StagedModel
     from repro.training import TrainState
 
@@ -119,7 +120,7 @@ def run_pipeline(args):
     state = create_train_state(params, opt)
     M = args.microbatches or max(S, args.batch // 2)
     plan = make_plan(S, M, args.k)
-    mesh = jax.make_mesh((S,), ("stage",))
+    mesh = stage_mesh(S)
     engine = make_pipeline_step(staged, plan, mesh)
 
     @jax.jit
@@ -167,6 +168,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0)
     args = ap.parse_args()
+    enable_persistent_cache()
     if args.mode == "pipeline":
         run_pipeline(args)
     else:

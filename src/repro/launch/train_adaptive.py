@@ -65,7 +65,13 @@ from repro.obs import (
     spans_by_track,
 )
 from repro.optim import make_optimizer
-from repro.runtime import PassiveLinkFeed, PlanRuntime, RealEngineHarness, TelemetryBus
+from repro.runtime import (
+    PassiveLinkFeed,
+    PlanRuntime,
+    RealEngineHarness,
+    TelemetryBus,
+    enable_persistent_cache,
+)
 
 ARTIFACT_DIR = os.path.join(
     os.path.dirname(__file__), "..", "..", "..", "experiments", "train_adaptive"
@@ -465,6 +471,7 @@ def main(argv=None) -> int:
         "https://ui.perfetto.dev)",
     )
     args = ap.parse_args(argv)
+    enable_persistent_cache()
     if os.environ.get("REPRO_SMOKE"):
         args.iterations = min(args.iterations, 6)
 
@@ -522,9 +529,9 @@ def main(argv=None) -> int:
 
     mesh = None
     if args.backend == "spmd":
-        import jax
+        from repro.pipeline import stage_mesh
 
-        mesh = jax.make_mesh((args.stages,), ("stage",))
+        mesh = stage_mesh(args.stages)
     sc = build_fig10_scenario(
         num_stages=args.stages, backend=args.backend, mesh=mesh, seed=args.seed
     )

@@ -11,8 +11,8 @@ Three entry points used by the assembly code:
 * ``cross_attn``   — encoder-decoder cross attention (seamless backbone).
 
 The prefill path routes through :mod:`repro.kernels.flash_attention.ops`
-when ``use_flash`` — a Pallas TPU kernel with a pure-jnp fallback oracle on
-CPU.  Decode uses the jnp path (one query token: bandwidth-bound gather, no
+when ``use_flash`` — a compiled Pallas TPU kernel, with no fallback on other
+backends.  Decode uses the jnp path (one query token: bandwidth-bound gather, no
 kernel needed).
 """
 

@@ -77,8 +77,8 @@ def _causal_conv(seq, w, b):
 
 def mamba_train(p, x, cfg: ModelConfig, use_kernel: bool = False):
     """x: [B, T, d] -> [B, T, d] (full-sequence chunked SSD)."""
-    from repro.kernels.ssd_scan import ops as ssd_ops
     from repro.kernels.ssd_scan import ref as ssd_ref
+    from repro.kernels.ssd_scan.kernel import ssd_chunked_pallas
 
     B_, T, d = x.shape
     d_in, H, P, N, G = _dims(cfg)
@@ -96,7 +96,7 @@ def mamba_train(p, x, cfg: ModelConfig, use_kernel: bool = False):
     xh = xx.reshape(B_, T, H, P)
     Bh = Bc.reshape(B_, T, G, N)
     Ch = Cc.reshape(B_, T, G, N)
-    fn = ssd_ops.ssd_chunked if use_kernel else ssd_ref.ssd_chunked
+    fn = ssd_chunked_pallas if use_kernel else ssd_ref.ssd_chunked
     y = fn(xh, dtv, A, Bh, Ch, chunk=cfg.ssm_chunk)  # [B,T,H,P]
     y = y + xh * p["D"].astype(y.dtype)[None, None, :, None]
     y = y.reshape(B_, T, d_in)

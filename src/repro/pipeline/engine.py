@@ -70,9 +70,8 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.experimental.shard_map import shard_map
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 import numpy as np
 
 from repro.core.schedule import Op, SchedulePlan
@@ -86,6 +85,7 @@ from repro.pipeline.stage import StagedModel
 __all__ = [
     "reference_pipeline_grads",
     "make_pipeline_step",
+    "stage_mesh",
     "queue_capacities",
     "arrival_tables",
 ]
@@ -413,6 +413,17 @@ def reference_pipeline_grads(
 # ---------------------------------------------------------------------------
 # Real SPMD engine (shard_map, lock-step ticks, ppermute transfers)
 # ---------------------------------------------------------------------------
+
+
+def stage_mesh(num_stages: int, data: int | None = None) -> Mesh:
+    """The engine's mesh over the local devices: a ``stage`` axis, plus a
+    ``data`` axis when ``data`` is given, in auto (GSPMD) mode.  The
+    engine's placement gathers and the runtime's re-stacking leave layouts
+    to the compiler; on JAX's default explicit axes each of them would need
+    a hand-written output sharding."""
+    shape = (num_stages,) if data is None else (num_stages, data)
+    names = ("stage",) if data is None else ("stage", "data")
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_pipeline_step(
@@ -829,12 +840,12 @@ def make_pipeline_step(
 
     param_spec = P(stage_axis)
     data_spec = P(None, data_axis) if data_axis else P()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         device_body,
         mesh=mesh,
         in_specs=(param_spec, data_spec, data_spec),
         out_specs=(P(), param_spec),
-        check_rep=False,
+        check_vma=False,
     )
 
     if v == 1:

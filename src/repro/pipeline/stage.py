@@ -22,6 +22,7 @@ dense layer is handled by folding it into a 61=1+60 prefix carried by stage
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -92,9 +93,12 @@ class StagedModel:
         }
 
     def init_all_stages(self, key):
-        """Stacked [S, ...] params pytree (leading dim = stage)."""
-        per_stage = [self.init_stage_params(key, s) for s in range(self.num_stages)]
-        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_stage)
+        """Stacked [S, ...] params pytree (leading dim = stage).
+
+        One compiled program: an eager call and a call traced inside a
+        caller's ``jit`` (the runtime's sharded init) fuse alike, so both
+        produce the same bits."""
+        return _init_all_stages(self, key)
 
     # -- compute --------------------------------------------------------------
 
@@ -107,7 +111,8 @@ class StagedModel:
                 x, _ = tf.apply_layer_train(rep_params[i], x, cfg, sp)
             return x, None
 
-        x, _ = jax.lax.scan(rep_step, x, params["blocks"])
+        body = jax.checkpoint(rep_step) if cfg.remat_blocks else rep_step
+        x, _ = jax.lax.scan(body, x, params["blocks"])
         return x
 
     def embed_tokens(self, params, tokens):
@@ -131,3 +136,9 @@ class StagedModel:
             x = self.stage_hidden(p_s, x)
         p_last = jax.tree_util.tree_map(lambda p: p[-1], all_params)
         return self.head_loss(p_last, x, labels)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _init_all_stages(staged: StagedModel, key):
+    per_stage = [staged.init_stage_params(key, s) for s in range(staged.num_stages)]
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_stage)

@@ -73,7 +73,12 @@ or the real ``shard_map`` engine; both consume the same lowered
 finally share one artifact end-to-end.
 """
 
-from repro.runtime.compile_cache import CacheStats, CompiledEntry, CompiledStepCache
+from repro.runtime.compile_cache import (
+    CacheStats,
+    CompiledEntry,
+    CompiledStepCache,
+    enable_persistent_cache,
+)
 from repro.runtime.executor import (
     IterationResult,
     PlanRuntime,
@@ -93,6 +98,7 @@ __all__ = [
     "CacheStats",
     "CompiledEntry",
     "CompiledStepCache",
+    "enable_persistent_cache",
     "IterationResult",
     "PlanRuntime",
     "SwitchEvent",

@@ -22,6 +22,10 @@ The cache is engine-agnostic: it is constructed with a ``program_factory``
 returning ``(jittable_fn, example_args)`` for a given
 :class:`~repro.core.schedule.TabularPlan`, which is how the reference and
 ``shard_map`` executors (and tests) plug in.
+
+Across processes, :func:`enable_persistent_cache` points JAX's persistent
+compilation cache at one fixed directory, so a second run of an entry point
+loads its executables instead of compiling them again.
 """
 
 from __future__ import annotations
@@ -29,14 +33,40 @@ from __future__ import annotations
 from concurrent.futures import Future, ThreadPoolExecutor
 import dataclasses
 import hashlib
+import os
+from pathlib import Path
 import threading
 import time
 from typing import Any, Callable, Iterable
 
+import jax
+
 from repro.core.schedule import TabularPlan
 from repro.obs.metrics import MetricsRegistry
 
-__all__ = ["CompiledEntry", "CacheStats", "CompiledStepCache"]
+__all__ = [
+    "CompiledEntry",
+    "CacheStats",
+    "CompiledStepCache",
+    "PERSISTENT_CACHE_DIR",
+    "enable_persistent_cache",
+]
+
+#: the persistent cache's home when ``JAX_COMPILATION_CACHE_DIR`` is unset: a
+#: fixed directory of the checkout (git-ignored).  Never a temporary or
+#: per-process name — a directory that moves between runs never hits.
+PERSISTENT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory: ``$JAX_COMPILATION_CACHE_DIR`` where that is set
+    (JAX reads the variable itself; no other directory is set), else
+    :data:`PERSISTENT_CACHE_DIR`.  Entry points call this before their
+    first compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(PERSISTENT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @dataclasses.dataclass

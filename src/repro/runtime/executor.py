@@ -48,9 +48,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.interfaces import TelemetrySink
 from repro.core.schedule import TabularPlan
@@ -81,25 +80,31 @@ def _leaf_role(path) -> str | None:
     return None
 
 
-def _collapse_rows(num_stages: int, v: int) -> np.ndarray:
-    """Row gather for replicated leaves, ``S*v -> S``: flat stage ``s``
+def _collapse_replicated(y, num_stages: int, v: int):
+    """Row selection for replicated leaves, ``S*v -> S``: flat stage ``s``
     takes its first chunk's copy, except the last flat stage, which must
-    keep the FINAL virtual stage's copy (the trained unembed head)."""
-    idx = [s * v for s in range(num_stages)]
-    idx[-1] = num_stages * v - 1
-    return np.asarray(idx)
+    keep the FINAL virtual stage's copy (the trained unembed head).
+
+    Written as a reshape plus a select along the chunk axis, not a row
+    gather: flat stage ``s``'s rows are the ``v`` rows its device already
+    holds, so a stage-sharded leaf collapses without communication (a
+    gather would make the compiler all-gather every replicated leaf)."""
+    y = y.reshape((num_stages, v) + y.shape[1:])
+    last = (jnp.arange(num_stages) == num_stages - 1).reshape(
+        (num_stages,) + (1,) * (y.ndim - 2)
+    )
+    return jnp.where(last, y[:, v - 1], y[:, 0])
 
 
 def restack_train_state(state, num_stages: int, v_from: int, v_to: int):
     """Re-stack a :class:`TrainState` (or any params-shaped pytree wrapped
     in one) between the ``v_from``- and ``v_to``-way virtual layouts.
 
-    Bitwise: block leaves reshape, replicated leaves repeat/gather, scalars
+    Bitwise: block leaves reshape, replicated leaves repeat/select, scalars
     pass through.  ``v_from == v_to`` returns the state unchanged."""
     if v_from == v_to:
         return state
     S = num_stages
-    gather = _collapse_rows(S, v_from) if v_from > 1 else None
 
     def leaf(path, x):
         role = _leaf_role(path)
@@ -114,7 +119,7 @@ def restack_train_state(state, num_stages: int, v_from: int, v_to: int):
                     )
                 y = y.reshape((S, v_from * y.shape[1]) + y.shape[2:])
             else:
-                y = y[gather]
+                y = _collapse_replicated(y, S, v_from)
         if v_to > 1:  # expand to the target layout
             if role == "blocks":
                 reps = y.shape[1]
@@ -186,6 +191,11 @@ class PlanRuntime:
             raise ValueError(f"unknown backend {backend!r}")
         if backend == "spmd" and mesh is None:
             raise ValueError("spmd backend needs a mesh with a 'stage' axis")
+        if mesh is not None and any(t != AxisType.Auto for t in mesh.axis_types):
+            raise ValueError(
+                f"mesh axes {mesh.axis_types} are not all auto: build the mesh "
+                "with repro.pipeline.stage_mesh"
+            )
         self.cfg = cfg
         self.num_stages = num_stages
         self.optimizer = optimizer
@@ -212,18 +222,19 @@ class PlanRuntime:
             self._flat_spec = None
         else:
             staged0 = self.staged_for(1)
-            params = staged0.init_all_stages(jax.random.PRNGKey(init_key))
-            self.state: TrainState = create_train_state(params, optimizer)
+
+            def init():
+                params = staged0.init_all_stages(jax.random.PRNGKey(init_key))
+                return create_train_state(params, optimizer)
+
             # layout specs are value-free, so the background compile thread
             # can read them while the main thread trains
-            self._flat_spec = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.state
-            )
-            if backend == "spmd":
-                # pin the owned state to the mesh layout every executable is
-                # AOT-compiled against: stage-stacked leaves shard over the
-                # stage axis, scalars replicate
-                self.state = jax.device_put(self.state, self._state_sharding(1))
+            self._flat_spec = jax.eval_shape(init)
+            # the state is born in the mesh layout every executable is
+            # AOT-compiled against (stage-stacked leaves sharded over the
+            # stage axis, scalars replicated): no device ever holds it whole
+            shardings = self._state_sharding(1) if backend == "spmd" else None
+            self.state: TrainState = jax.jit(init, out_shardings=shardings)()
         self.current_v = 1
         # a fresh cache joins the shared registry (series scoped by track so
         # an in-process fleet's per-host stats stay per-host); a borrowed
@@ -337,27 +348,28 @@ class PlanRuntime:
             return new_state, loss, grads
 
         args = (self._state_spec_for(v),) + self._data_spec_for(plan)
-        return jax.jit(step), args
+        # the old state dies with the step: donating it lets the new state
+        # reuse its buffers instead of holding both on the device
+        return jax.jit(step, donate_argnums=0), args
 
     # -- the warm switch path -------------------------------------------------
 
     def _restack_program(self, v_from: int, v_to: int):
         """AOT-compiled layout change ``v_from -> v_to`` (compiled at most
-        once per direction; warmed in the background by ``precompile``)."""
+        once per direction; compiled in the background by ``precompile``)."""
         key = (v_from, v_to)
         with self._restack_lock:
             prog = self._restack_compiled.get(key)
         if prog is None:
             S = self.num_stages
-            fn = jax.jit(lambda s: restack_train_state(s, S, v_from, v_to))
-            spec = self._state_spec_for(v_from)
-            prog = fn.lower(spec).compile()
-            # first-invocation lazy init costs ~ms: pay it here (usually on
-            # the background worker), not on the switch path
-            zeros = jax.tree_util.tree_map(
-                lambda sp: jnp.zeros(sp.shape, sp.dtype), spec
+            out = self._state_sharding(v_to) if self.backend == "spmd" else None
+            fn = jax.jit(
+                lambda s: restack_train_state(s, S, v_from, v_to), out_shardings=out
             )
-            jax.block_until_ready(prog(zeros))
+            # compiled, not run: warming it on a zero state would hold a
+            # third full state (v_from in, v_to out) beside the live one,
+            # more than a v5e holds for GPT-XL on four chips
+            prog = fn.lower(self._state_spec_for(v_from)).compile()
             with self._restack_lock:
                 self._restack_compiled.setdefault(key, prog)
         return prog
@@ -468,6 +480,8 @@ class PlanRuntime:
             if self.obs is not None
             else None
         )
+        # the previous step's grads must not stay live beside this step's
+        self.last_grads = None
         t0 = time.perf_counter()
         state, loss, grads = self._compiled(self.state, tokens, labels)
         loss = jax.block_until_ready(loss)

@@ -96,6 +96,6 @@ def test_flash_wrapper_layout_roundtrip():
 
     B, T, H, hd = 2, 64, 4, 32
     q, k, v = _mk_qkv(jax.random.PRNGKey(3), B, T, T, H, hd, jnp.float32)
-    out = flash_attention(q, k, v, causal=True, force_kernel=True)
+    out = flash_attention(q, k, v, causal=True, interpret=True)
     ref = flash_ref.attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6, rtol=2e-6)

@@ -309,7 +309,7 @@ _SPMD_SCRIPT = textwrap.dedent(
     from repro.core.schedule import make_plan
     from repro.models.common import ModelConfig
     from repro.pipeline.stage import StagedModel
-    from repro.pipeline.engine import make_pipeline_step
+    from repro.pipeline.engine import make_pipeline_step, stage_mesh
 
     cfg = ModelConfig("tiny", "dense", num_layers=4, d_model=48, num_heads=4,
                       num_kv_heads=2, d_ff=96, vocab_size=128,
@@ -322,10 +322,7 @@ _SPMD_SCRIPT = textwrap.dedent(
     labels = jnp.asarray(rng.integers(0, 128, (M, b, T)), jnp.int32)
 
     def check(plan, staged, params, oloss, ograds, dp=None):
-        if dp:
-            mesh = jax.make_mesh((S, 2), ("stage", "data"))
-        else:
-            mesh = jax.make_mesh((S,), ("stage",))
+        mesh = stage_mesh(S, 2 if dp else None)
         step = jax.jit(make_pipeline_step(staged, plan, mesh, data_axis=dp))
         with mesh:
             sloss, sgrads = step(params, tokens, labels)

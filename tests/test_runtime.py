@@ -390,6 +390,7 @@ from repro.core.kinds import ScheduleSpec
 from repro.core.schedule import make_plan
 from repro.models.common import ModelConfig
 from repro.optim import make_optimizer
+from repro.pipeline import stage_mesh
 from repro.runtime import PlanRuntime
 
 cfg = ModelConfig("rt-spmd", "dense", num_layers=4, d_model=16, num_heads=2,
@@ -398,7 +399,7 @@ cfg = ModelConfig("rt-spmd", "dense", num_layers=4, d_model=16, num_heads=2,
 S, M, b, T = 2, 4, 2, 8
 B = M * b
 opt = make_optimizer("adamw", schedule=lambda s: jnp.float32(1e-3))
-mesh = jax.make_mesh((S,), ("stage",))
+mesh = stage_mesh(S)
 rt = PlanRuntime(cfg, S, opt, global_batch=B, seq_len=T, backend="spmd", mesh=mesh)
 plans = [
     make_plan(S, M, 1, micro_batch_size=b),
@@ -456,6 +457,115 @@ def test_spmd_runtime_switch_subprocess():
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "SPMD_RUNTIME_OK" in proc.stdout
+
+
+_SPMD_PLACEMENT_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from repro.models.common import ModelConfig
+from repro.optim import make_optimizer
+from repro.pipeline import StagedModel, stage_mesh
+from repro.runtime import PlanRuntime
+from repro.training import create_train_state
+
+cfg = ModelConfig("rt-place", "dense", num_layers=8, d_model=16, num_heads=2,
+                  num_kv_heads=2, d_ff=32, vocab_size=64,
+                  dtype=jnp.float32, param_dtype=jnp.float32)
+S = 4
+opt = make_optimizer("adamw", schedule=lambda s: jnp.float32(1e-3))
+rt = PlanRuntime(cfg, S, opt, global_batch=8, seq_len=8, backend="spmd", mesh=stage_mesh(S))
+
+
+def assert_placed(tree, v, what):
+    want = jax.tree_util.tree_leaves(rt._state_sharding(v))
+    for (path, x), sh in zip(jax.tree_util.tree_leaves_with_path(tree), want):
+        name = jax.tree_util.keystr(path)
+        assert x.sharding.is_equivalent_to(sh, x.ndim), (what, name, x.sharding, sh)
+        if x.ndim and x.shape[0] == S * v:  # stage-stacked: v rows on each device
+            assert {d.data.shape[0] for d in x.addressable_shards} == {v}, (what, name)
+
+
+def assert_bitwise(a, b):
+    for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+assert_placed(rt.state, 1, "init")
+# born sharded, yet the same bits as a single-device init
+staged = StagedModel.build(cfg, S)
+assert_bitwise(rt.state, create_train_state(staged.init_all_stages(jax.random.PRNGKey(0)), opt))
+there = rt._restack_program(1, 2)(rt.state)
+assert_placed(there, 2, "re-stacked to v=2")
+back = rt._restack_program(2, 1)(there)
+assert_placed(back, 1, "re-stacked back to v=1")
+assert_bitwise(back, rt.state)
+rt.cache.shutdown()
+print("SPMD_PLACEMENT_OK")
+"""
+
+
+def test_spmd_state_placement_subprocess():
+    """On a 4-device stage mesh the runtime's state is born sharded (no
+    device ever holds it whole) and re-stacking lands each leaf in the
+    target layout's sharding — all with the bits of the single-device
+    path."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src")
+    )
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-c", _SPMD_PLACEMENT_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "SPMD_PLACEMENT_OK" in proc.stdout
+
+
+_CACHE_SCRIPT = """
+import os, sys
+import jax, jax.numpy as jnp
+from repro.runtime import enable_persistent_cache
+from repro.runtime.compile_cache import PERSISTENT_CACHE_DIR
+
+before = set(os.listdir(PERSISTENT_CACHE_DIR)) if PERSISTENT_CACHE_DIR.exists() else set()
+path = enable_persistent_cache()
+assert path == os.environ["JAX_COMPILATION_CACHE_DIR"], path
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.jit(lambda x: jnp.sin(x) * 3).lower(jnp.ones(7)).compile()
+assert os.listdir(path), "no cache entry written"
+after = set(os.listdir(PERSISTENT_CACHE_DIR)) if PERSISTENT_CACHE_DIR.exists() else set()
+assert after == before, "wrote outside JAX_COMPILATION_CACHE_DIR"
+print("CACHE_OK")
+"""
+
+
+def test_persistent_cache_follows_jax_compilation_cache_dir(tmp_path):
+    """Where ``JAX_COMPILATION_CACHE_DIR`` is set, compiled programs land
+    there and nowhere else; unset, the cache is one fixed directory of the
+    checkout."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.runtime import compile_cache
+
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"))
+    env["PYTHONPATH"] = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
+    proc = subprocess.run(
+        [sys.executable, "-c", _CACHE_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "CACHE_OK" in proc.stdout
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    assert str(compile_cache.PERSISTENT_CACHE_DIR) == os.path.join(root, ".jax_cache")
 
 
 @pytest.mark.slow
