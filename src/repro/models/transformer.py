@@ -118,6 +118,7 @@ def init_layer(key, cfg: ModelConfig, spec: LayerSpec, cross: bool = False):
     return p
 
 
+@jax.named_scope("mlp")
 def _ffn(p, x, cfg: ModelConfig, spec: LayerSpec):
     """FFN sublayer; returns (delta, aux_losses)."""
     zero = jnp.zeros((), jnp.float32)
@@ -144,13 +145,15 @@ def apply_layer_train(
 ):
     h = norm_apply(p["ln1"], x, cfg)
     if spec.kind == "attn":
-        h = attn.attn_train(
-            p["attn"], h, cfg,
-            window=spec.window, causal=causal,
-            positions=positions, mrope_positions=mrope_positions, use_flash=use_flash,
-        )
+        with jax.named_scope("attention"):
+            h = attn.attn_train(
+                p["attn"], h, cfg,
+                window=spec.window, causal=causal,
+                positions=positions, mrope_positions=mrope_positions, use_flash=use_flash,
+            )
     else:
-        h = mamba_mod.mamba_train(p["mamba"], h, cfg)
+        with jax.named_scope("mamba"):
+            h = mamba_mod.mamba_train(p["mamba"], h, cfg)
     x = x + h
     if memory is not None and "xattn" in p:
         x = x + attn.cross_attn(p["xattn"], norm_apply(p["ln_x"], x, cfg), memory, cfg)
@@ -176,10 +179,12 @@ def apply_layer_decode(
     h = norm_apply(p["ln1"], x, cfg)
     new_cache = dict(cache)
     if spec.kind == "attn":
-        h, new_kv = attn.attn_decode(p["attn"], h, cache["kv"], index, cfg, window=spec.window)
+        with jax.named_scope("attention"):
+            h, new_kv = attn.attn_decode(p["attn"], h, cache["kv"], index, cfg, window=spec.window)
         new_cache["kv"] = new_kv
     else:
-        h, new_ssm = mamba_mod.mamba_decode(p["mamba"], h, cache["ssm"], cfg)
+        with jax.named_scope("mamba"):
+            h, new_ssm = mamba_mod.mamba_decode(p["mamba"], h, cache["ssm"], cfg)
         new_cache["ssm"] = new_ssm
     x = x + h
     if memory is not None and "xattn" in p:
@@ -305,10 +310,12 @@ def apply_layer_prefill(p, x, cache, cfg: ModelConfig, spec: LayerSpec):
     h = norm_apply(p["ln1"], x, cfg)
     new_cache = dict(cache)
     if spec.kind == "attn":
-        h, new_kv = attn.attn_prefill(p["attn"], h, cache["kv"], cfg, window=spec.window)
+        with jax.named_scope("attention"):
+            h, new_kv = attn.attn_prefill(p["attn"], h, cache["kv"], cfg, window=spec.window)
         new_cache["kv"] = new_kv
     else:
-        h, new_ssm = mamba_mod.mamba_prefill(p["mamba"], h, cache["ssm"], cfg)
+        with jax.named_scope("mamba"):
+            h, new_ssm = mamba_mod.mamba_prefill(p["mamba"], h, cache["ssm"], cfg)
         new_cache["ssm"] = new_ssm
     x = x + h
     delta, _ = _ffn(p, x, cfg, spec)

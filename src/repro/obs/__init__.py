@@ -7,7 +7,9 @@ subsystem (see ``obs/README.md`` for the Perfetto walkthrough):
 module               provides
 ===================  =======================================================
 ``trace``            :class:`TraceRecorder` spans/instants -> Chrome/Perfetto
-                     JSON; :func:`render_simulated_trace` for the predicted
+                     JSON; :func:`span`, the program's profiler-clock span
+                     site (optionally mirrored onto a recorder);
+                     :func:`render_simulated_trace` for the predicted
                      timeline; schema + overlap validators (CI gate)
 ``metrics``          :class:`MetricsRegistry` — labeled counter/gauge/
                      histogram series with snapshot/delta export; the single
@@ -22,7 +24,8 @@ module               provides
 
 Everything here is stdlib-only at module level, so any layer (core, runtime,
 fabric, launch) may depend on it without import cycles; only
-:func:`render_simulated_trace` touches the core stack, lazily.
+:func:`render_simulated_trace` touches the core stack and :func:`span`
+JAX's profiler, both lazily.
 
 :class:`Observability` bundles one of each for plumbing through
 constructors: ``obs = Observability.create(trace_clock=...)`` then pass
@@ -42,6 +45,7 @@ from repro.obs.trace import (
     TraceValidationError,
     merge_traces,
     render_simulated_trace,
+    span,
     spans_by_track,
     validate_chrome_trace,
     validate_no_overlap,
@@ -53,6 +57,7 @@ __all__ = [
     "TraceValidationError",
     "merge_traces",
     "render_simulated_trace",
+    "span",
     "spans_by_track",
     "validate_chrome_trace",
     "validate_no_overlap",

@@ -11,6 +11,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable
 
+import jax
+
 from repro.optim.adafactor import adafactor_init, adafactor_update
 from repro.optim.adamw import adamw_init, adamw_update
 from repro.optim.clipping import clip_by_global_norm
@@ -42,6 +44,7 @@ def make_optimizer(
     else:
         raise ValueError(f"unknown optimizer {name!r}")
 
+    @jax.named_scope("optimizer")
     def update(params, grads, state):
         lr = schedule(state.step)
         metrics = {"lr": lr}

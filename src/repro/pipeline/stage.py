@@ -115,9 +115,11 @@ class StagedModel:
         x, _ = jax.lax.scan(body, x, params["blocks"])
         return x
 
+    @jax.named_scope("embed")
     def embed_tokens(self, params, tokens):
         return embed(params["embed"], tokens, self.cfg)
 
+    @jax.named_scope("head_loss")
     def head_loss(self, params, h, labels):
         """Last-stage epilogue: final norm + unembed + mean token CE."""
         cfg = self.cfg

@@ -54,7 +54,7 @@ from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.core.interfaces import TelemetrySink
 from repro.core.schedule import TabularPlan
 from repro.models.common import ModelConfig
-from repro.obs import Observability
+from repro.obs import Observability, span
 from repro.pipeline.engine import make_pipeline_step, reference_pipeline_grads
 from repro.pipeline.stage import StagedModel
 from repro.runtime.compile_cache import CompiledStepCache
@@ -253,15 +253,12 @@ class PlanRuntime:
         self.switch_events: list[SwitchEvent] = []
         self.iterations: list[IterationResult] = []
         self.last_grads = None
-        # observability (optional): trace spans on "{obs_track}/switches" and
-        # "{obs_track}/iterations", registry series, flight plan_switch events
+        # every span lands in a profiler trace (``repro.runtime.*``); with
+        # ``obs`` it is also recorded on "{obs_track}/switches" or
+        # "{obs_track}/iterations", and switches go to the flight ring
         self.obs = obs
         self.obs_track = obs_track
-        if obs is not None:
-            self._m_iters = obs.metrics.counter("runtime_iterations_total")
-            self._m_iter_s = obs.metrics.histogram("runtime_iteration_seconds")
-            self._m_switches = obs.metrics.counter("runtime_switches_total")
-            self._m_switch_s = obs.metrics.histogram("runtime_switch_seconds")
+        self._recorder = obs.trace if obs is not None else None
 
     # -- model/program plumbing ----------------------------------------------
 
@@ -393,31 +390,33 @@ class PlanRuntime:
         pays the synchronous compile (recorded separately so the warm
         latency the acceptance gate tracks is not polluted)."""
         warm = self.cache.contains(table)
-        sp = (
-            self.obs.trace.span(
-                f"{self.obs_track}/switches",
-                f"switch {table.plan.name}",
-                to_plan=table.plan.name,
-                warm=warm,
-            )
-            if self.obs is not None
-            else None
-        )
-        t0 = time.perf_counter()
-        entry = self.cache.get(table)
-        t1 = time.perf_counter()
         v_new = table.plan.num_virtual
         # stateless (serving) runtimes track the layout but have no owned
         # state to re-stack — the engine's params are layout-independent
         restacked = v_new != self.current_v and self.state is not None
-        if restacked:
-            prog = self._restack_program(self.current_v, v_new)
-            self.state = jax.block_until_ready(prog(self.state))
-        self.current_v = v_new
-        seconds = time.perf_counter() - t0
+        from_plan = self.current_table.plan.name if self.current_table else ""
+        with span(
+            "repro.runtime.switch",
+            recorder=self._recorder,
+            track=f"{self.obs_track}/switches",
+            title=f"switch {table.plan.name}",
+            to_plan=table.plan.name,
+            from_plan=from_plan,
+            warm=warm,
+            restacked=restacked,
+            iteration=len(self.iterations),
+        ):
+            t0 = time.perf_counter()
+            entry = self.cache.get(table)
+            t1 = time.perf_counter()
+            if restacked:
+                prog = self._restack_program(self.current_v, v_new)
+                self.state = jax.block_until_ready(prog(self.state))
+            self.current_v = v_new
+            seconds = time.perf_counter() - t0
         event = SwitchEvent(
             iteration=len(self.iterations),
-            from_plan=self.current_table.plan.name if self.current_table else "",
+            from_plan=from_plan,
             to_plan=table.plan.name,
             from_kind=self.current_table.plan.kind if self.current_table else "",
             to_kind=table.plan.kind,
@@ -432,14 +431,6 @@ class PlanRuntime:
         self._compiled = entry.compiled
         self.switch_events.append(event)
         if self.obs is not None:
-            self.obs.trace.end_span(
-                sp,
-                from_plan=event.from_plan,
-                restacked=restacked,
-                iteration=event.iteration,
-            )
-            self._m_switches.inc(warm=str(warm).lower())
-            self._m_switch_s.observe(event.seconds, warm=str(warm).lower())
             self.obs.flight.record(
                 "plan_switch",
                 iteration=event.iteration,
@@ -462,44 +453,45 @@ class PlanRuntime:
         if self.current_table is None:
             raise RuntimeError("no plan dispatched; call switch_to first")
         plan = self.current_table.plan
-        M = plan.num_microbatches
-        b = self.global_batch // M
-        tokens = jnp.asarray(tokens).reshape(M, b, self.seq_len)
-        labels = jnp.asarray(labels).reshape(M, b, self.seq_len)
-        if self.backend == "spmd":
-            sharding = self._data_sharding()
-            tokens = jax.device_put(tokens, sharding)
-            labels = jax.device_put(labels, sharding)
-        sp = (
-            self.obs.trace.span(
-                f"{self.obs_track}/iterations",
-                f"iter {len(self.iterations)} {plan.name}",
-                plan=plan.name,
-                index=len(self.iterations),
-            )
-            if self.obs is not None
-            else None
-        )
-        # the previous step's grads must not stay live beside this step's
-        self.last_grads = None
-        t0 = time.perf_counter()
-        state, loss, grads = self._compiled(self.state, tokens, labels)
-        loss = jax.block_until_ready(loss)
-        seconds = time.perf_counter() - t0
-        self.state = state
-        self.last_grads = grads
+        index = len(self.iterations)
+        with span(
+            "repro.runtime.iteration",
+            recorder=self._recorder,
+            track=f"{self.obs_track}/iterations",
+            title=f"iter {index} {plan.name}",
+            step=index,
+            plan=plan.name,
+            index=index,
+        ) as sp:
+            with span("repro.runtime.feed"):
+                M = plan.num_microbatches
+                b = self.global_batch // M
+                tokens = jnp.asarray(tokens).reshape(M, b, self.seq_len)
+                labels = jnp.asarray(labels).reshape(M, b, self.seq_len)
+                if self.backend == "spmd":
+                    sharding = self._data_sharding()
+                    tokens = jax.device_put(tokens, sharding)
+                    labels = jax.device_put(labels, sharding)
+            # the previous step's grads must not stay live beside this step's
+            self.last_grads = None
+            t0 = time.perf_counter()
+            with span("repro.runtime.launch"):
+                state, loss, grads = self._compiled(self.state, tokens, labels)
+            with span("repro.runtime.sync"):
+                loss = float(jax.block_until_ready(loss))
+            seconds = time.perf_counter() - t0
+            self.state = state
+            self.last_grads = grads
+            if sp is not None:
+                sp.args["loss"] = loss
         result = IterationResult(
-            index=len(self.iterations),
+            index=index,
             plan_name=plan.name,
             kind=plan.kind,
-            loss=float(loss),
+            loss=loss,
             seconds=seconds,
         )
         self.iterations.append(result)
-        if self.obs is not None:
-            self.obs.trace.end_span(sp, loss=result.loss)
-            self._m_iters.inc(plan=plan.name)
-            self._m_iter_s.observe(seconds, plan=plan.name)
         if self.telemetry is not None:
             self.telemetry.publish_iteration(
                 index=result.index,
@@ -521,24 +513,17 @@ class PlanRuntime:
         if self._compiled is None:
             raise RuntimeError("no plan dispatched; call switch_to first")
         plan = self.current_table.plan
-        sp = (
-            self.obs.trace.span(
-                f"{self.obs_track}/iterations",
-                f"{label} {plan.name}",
-                plan=plan.name,
-                label=label,
-            )
-            if self.obs is not None
-            else None
-        )
-        t0 = time.perf_counter()
-        out = self._compiled(*args)
-        out = jax.block_until_ready(out)
-        seconds = time.perf_counter() - t0
-        if self.obs is not None:
-            self.obs.trace.end_span(sp)
-            self._m_iters.inc(plan=plan.name)
-            self._m_iter_s.observe(seconds, plan=plan.name)
+        with span(
+            "repro.runtime.program",
+            recorder=self._recorder,
+            track=f"{self.obs_track}/iterations",
+            title=f"{label} {plan.name}",
+            plan=plan.name,
+            label=label,
+        ):
+            t0 = time.perf_counter()
+            out = jax.block_until_ready(self._compiled(*args))
+            seconds = time.perf_counter() - t0
         return out, seconds
 
     # -- inspection -----------------------------------------------------------

@@ -22,17 +22,22 @@ per-slot positions, per-slot last token) and runs two kinds of programs:
 
 Decoding is greedy (temperature 0) so serving runs are reproducible
 token-for-token; emitted tokens accumulate in ``outputs[rid]``.
+
+Each call opens ``repro.serve.*`` profiler spans (:func:`repro.obs.span`)
+around its host steps, so a trace puts the device's idle time down to them:
+``decode_tick`` carries ``occupied``/``max_slots``/``host_reads``, a
+prefill request ``prompt_len`` and ``new_program`` (1 when its length
+compiles a new prefill program).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.models import api
 from repro.models.common import ModelConfig
+from repro.obs import span
 from repro.runtime.executor import PlanRuntime
 
 __all__ = ["ServeEngine"]
@@ -64,6 +69,7 @@ class ServeEngine:
         self.tokens = jnp.zeros((max_slots, 1), jnp.int32)
         self.outputs: dict[int, list[int]] = {}
         self._slot_rid: list[int | None] = [None] * max_slots
+        self._prefills: dict[int, object] = {}  # prompt length -> jitted prefill
         # stateless runtime: no TrainState, programs come from our factory,
         # but the compile cache / warm-switch machinery is the training one
         self.runtime = PlanRuntime(
@@ -124,8 +130,9 @@ class ServeEngine:
     def switch_to(self, table):
         return self.runtime.switch_to(table)
 
-    @functools.lru_cache(maxsize=32)
     def _prefill_program(self, prompt_len: int):
+        """The jitted batch-1 prefill of one prompt length (compiled on its
+        first call)."""
         cfg, max_len = self.cfg, self.max_len
 
         def prefill(params, tokens):
@@ -140,36 +147,59 @@ class ServeEngine:
         the prompt is a deterministic seeded token sequence per request."""
         for inf in admitted:
             req = inf.request
-            key = jax.random.PRNGKey(req.rid)
-            prompt = jax.random.randint(
-                key, (1, req.prompt_len), 0, self.cfg.vocab_size, jnp.int32
-            )
-            tok, row = self._prefill_program(req.prompt_len)(self.params, prompt)
-            s = inf.slot
-            self.kv = jax.tree_util.tree_map(
-                lambda full, r: full.at[s].set(r), self.kv, row
-            )
-            self.positions = self.positions.at[s].set(req.prompt_len)
-            self.tokens = self.tokens.at[s].set(tok)
-            self._slot_rid[s] = req.rid
-            self.outputs[req.rid] = [int(tok[0])]
+            program = self._prefills.get(req.prompt_len)
+            new_program = program is None
+            if new_program:
+                program = self._prefills[req.prompt_len] = self._prefill_program(
+                    req.prompt_len
+                )
+            with span(
+                "repro.serve.prefill.request",
+                prompt_len=req.prompt_len,
+                new_program=int(new_program),
+            ):
+                with span("repro.serve.prefill.prompt"):
+                    key = jax.random.PRNGKey(req.rid)
+                    prompt = jax.random.randint(
+                        key, (1, req.prompt_len), 0, self.cfg.vocab_size, jnp.int32
+                    )
+                with span("repro.serve.prefill.program"):
+                    tok, row = program(self.params, prompt)
+                s = inf.slot
+                with span("repro.serve.prefill.insert"):
+                    self.kv = jax.tree_util.tree_map(
+                        lambda full, r: full.at[s].set(r), self.kv, row
+                    )
+                    self.positions = self.positions.at[s].set(req.prompt_len)
+                    self.tokens = self.tokens.at[s].set(tok)
+                self._slot_rid[s] = req.rid
+                with span("repro.serve.prefill.emit"):
+                    self.outputs[req.rid] = [int(tok[0])]
 
     def decode_tick(self, in_flight) -> None:
         """One grouped decode step of the CURRENT plan over all slots (empty
         slots compute padding, as a fixed-shape batch would)."""
-        (new_kv, new_tok), _seconds = self.runtime.run_program(
-            self.params, self.kv, self.positions, self.tokens, label="decode"
-        )
-        self.kv = new_kv
-        self.tokens = new_tok
-        occupied = jnp.zeros((self.max_slots,), bool)
-        for inf in in_flight:
-            occupied = occupied.at[inf.slot].set(True)
-            self.outputs[inf.request.rid].append(int(new_tok[inf.slot, 0]))
-        self.positions = jnp.where(occupied, self.positions + 1, self.positions)
+        with span(
+            "repro.serve.decode_tick",
+            occupied=len(in_flight),
+            max_slots=self.max_slots,
+            host_reads=len(in_flight),  # one token read per occupied slot
+        ):
+            (new_kv, new_tok), _seconds = self.runtime.run_program(
+                self.params, self.kv, self.positions, self.tokens, label="decode"
+            )
+            self.kv = new_kv
+            self.tokens = new_tok
+            with span("repro.serve.decode.emit"):
+                occupied = jnp.zeros((self.max_slots,), bool)
+                for inf in in_flight:
+                    occupied = occupied.at[inf.slot].set(True)
+                    self.outputs[inf.request.rid].append(int(new_tok[inf.slot, 0]))
+                self.positions = jnp.where(occupied, self.positions + 1, self.positions)
 
     def release(self, slots) -> None:
-        for s in slots:
-            self._slot_rid[s] = None
-            self.positions = self.positions.at[s].set(0)
-            self.tokens = self.tokens.at[s].set(0)
+        with span("repro.serve.release", slots=len(slots)):
+            for s in slots:
+                self._slot_rid[s] = None
+                self.positions = self.positions.at[s].set(0)
+                self.tokens = self.tokens.at[s].set(0)
