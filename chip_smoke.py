@@ -293,7 +293,7 @@ def _check_serve_logits(engine, cfg, api, inf, prompt_len) -> None:
     )
     emitted = jnp.asarray(engine.outputs[rid], jnp.int32)[None]
     seq = jnp.concatenate([prompt, emitted], axis=1)
-    row = jax.tree_util.tree_map(lambda x: x[slot], engine.kv)
+    row = engine.slot_cache(slot)
     with jax.default_matmul_precision("highest"):
         step = jax.jit(lambda p, c, i, t: api.decode_fn(p, cfg, c, i, {"tokens": t})[0])
         got = step(engine.params, row, engine.positions[slot], engine.tokens[slot][None])

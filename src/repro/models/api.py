@@ -14,7 +14,7 @@ Batch keys by family:
   vlm:    embeds [B,T,d], labels [B,T], mrope_positions [3,B,T]
   encdec: src_embeds [B,S,d], tgt_tokens [B,T], labels [B,T]
 Decode batches carry ``tokens`` [B,1] (all families) plus ``memory``
-[B,S,d] for enc-dec.
+[B,S,d] for enc-dec; ``index`` is a scalar or, decoder-only, [B].
 """
 
 from __future__ import annotations
@@ -119,10 +119,15 @@ def cache_specs(cfg: ModelConfig, batch_size: int, max_len: int):
     return jax.eval_shape(lambda: init_cache(cfg, batch_size, max_len))
 
 
-def decode_fn(params, cfg: ModelConfig, cache, index, batch: Mapping[str, jax.Array]):
+def decode_fn(
+    params, cfg: ModelConfig, cache, index, batch: Mapping[str, jax.Array], groups=1
+):
+    """One token per row.  ``index`` is a scalar position or one per row;
+    decoder-only families may walk the rows as ``groups`` micro-batches
+    (see ``transformer.decode_step``)."""
     if cfg.family == "encdec":
         logits, new_cache = tf.encdec_decode_step(
             params, cfg, cache, index, batch["tokens"], batch["memory"]
         )
         return logits, new_cache
-    return tf.decode_step(params, cfg, cache, index, tokens=batch["tokens"])
+    return tf.decode_step(params, cfg, cache, index, tokens=batch["tokens"], groups=groups)

@@ -6,8 +6,9 @@ Three entry points used by the assembly code:
 * ``attn_prefill`` — full-sequence attention that ALSO fills the decode KV
   cache (one fused pass replaces T single-token steps — the serving
   prefill path).
-* ``attn_decode``  — single-token decode against a pre-filled KV cache
-  (``jax.lax.dynamic_update_slice`` in-place cache update).
+* ``attn_decode``  — single-token decode against a pre-filled KV cache:
+  one new K/V row per batch row written in place, at a shared or a per-row
+  position, into one layer and a window of rows of a (stacked) cache.
 * ``cross_attn``   — encoder-decoder cross attention (seamless backbone).
 
 The prefill path routes through :mod:`repro.kernels.flash_attention.ops`
@@ -27,6 +28,7 @@ from repro.models.common import ModelConfig
 from repro.models.layers import (
     apply_mrope,
     apply_rope,
+    cache_rows,
     dense,
     dense_init,
     rope_frequencies,
@@ -252,25 +254,64 @@ def attn_prefill(p, x, cache, cfg: ModelConfig, *, window: int | None = None):
     return dense(p["wo"], _merge_heads(out), cfg), {"k": new_k, "v": new_v}
 
 
-def attn_decode(p, x, cache, index, cfg: ModelConfig, *, window: int | None = None):
-    """One-token decode.  x: [B, 1, d]; ``index``: scalar position of the new
-    token.  Returns (out, new_cache).  Windowed layers use a ring buffer."""
-    B = x.shape[0]
-    positions = jnp.full((B, 1), index, jnp.int32)
+def attn_decode(
+    p, x, cache, index, cfg: ModelConfig, *, window: int | None = None, at=(0,)
+):
+    """One-token decode.  x: [b, 1, d]; ``index``: the new token's position,
+    a scalar or one per row ([b]).  ``cache`` leaves are [*lead, B, L, K, hd]
+    and ``at`` picks the b rows x decodes (see :func:`cache_rows`; the
+    default is every row of an unstacked cache).  Each row's new K/V is
+    written in place at ``index % L`` (a ring buffer when windowed), then
+    attention reads the rows.  Returns (out, new_cache), the whole cache."""
+    b = x.shape[0]
+    index = jnp.asarray(index, jnp.int32)
+    positions = jnp.broadcast_to(index.reshape(-1, 1), (b, 1))
     mrope_positions = None
     if cfg.mrope:
-        mrope_positions = jnp.broadcast_to(positions, (3, B, 1))
+        mrope_positions = jnp.broadcast_to(positions, (3, b, 1))
     q, k, v = _project_qkv(p, x, cfg, positions, mrope_positions)
-    L = cache["k"].shape[1]
-    slot = jnp.asarray(index, jnp.int32) % L  # ring buffer when windowed; id otherwise
-    new_k = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
-    new_v = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
-    n_rep = cfg.num_heads // cfg.num_kv_heads
+    L = cache["k"].shape[-3]
+    slot = index % L  # ring buffer when windowed; id otherwise
+    new = {"k": _write_token(cache["k"], k, at, slot), "v": _write_token(cache["v"], v, at, slot)}
     # valid positions: for a ring buffer every slot < min(index+1, L) is valid
-    valid = jnp.arange(L)[None, None, None, :] < jnp.minimum(index + 1, L)
-    out = sdpa(q, new_k, new_v, mask=valid)
+    valid = jnp.arange(L) < jnp.minimum(index + 1, L)[..., None]
+    out = sdpa(
+        q, cache_rows(new["k"], at, b), cache_rows(new["v"], at, b),
+        mask=valid.reshape(-1, 1, 1, L),
+    )
     out = dense(p["wo"], _merge_heads(out), cfg)
-    return out, {"k": new_k, "v": new_v}
+    return out, new
+
+
+# positions a per-row decode write covers: one TPU lane-width tile
+WRITE_TILE = 128
+
+
+def _write_token(buf, t, at, pos):
+    """Write the new token's [b, 1, K, hd] projection ``t`` of the rows at
+    ``at`` into ``buf`` at position ``pos``: a scalar, or one per row.
+
+    A shared position is one ``dynamic_update_slice``, which GSPMD splits
+    over a sharded position axis.  Per-row positions are the serving
+    engine's, on one TPU: it lays a cache out with the position axis minor
+    (K·hd is narrower than its lanes), and a one-position write there
+    would make XLA lay the whole cache out again around the decode loop,
+    copying it twice a tick.  So each row rewrites, in place, the aligned
+    tile of up to ``WRITE_TILE`` positions that holds its token."""
+    *lead, row0 = at
+    t = t.astype(buf.dtype)
+    if pos.ndim == 0:
+        t = t.reshape((1,) * len(lead) + t.shape)
+        return jax.lax.dynamic_update_slice(buf, t, (*lead, row0, pos, 0, 0))
+    L = buf.shape[-3]
+    w = min(L, WRITE_TILE)
+    for r in range(t.shape[0]):
+        s0 = jnp.minimum(pos[r] // w * w, L - w)
+        start = (*lead, row0 + r, s0, 0, 0)
+        tile = jax.lax.dynamic_slice(buf, start, (1,) * (len(lead) + 1) + (w,) + t.shape[2:])
+        hit = (jnp.arange(w) == pos[r] - s0)[:, None, None]
+        buf = jax.lax.dynamic_update_slice(buf, jnp.where(hit, t[r], tile), start)
+    return buf
 
 
 # -- cross attention (enc-dec) ---------------------------------------------------

@@ -1,4 +1,5 @@
-"""Primitive layers: norms, MLPs, embeddings, rotary position embeddings.
+"""Primitive layers: norms, MLPs, embeddings, rotary position embeddings,
+and the row addressing of decode caches.
 
 Everything is functional: ``init_*`` returns a param pytree, ``apply``-style
 functions are pure.  Parameters are stored in ``cfg.param_dtype`` and cast to
@@ -33,6 +34,8 @@ __all__ = [
     "apply_rope",
     "apply_mrope",
     "cross_entropy_loss",
+    "cache_rows",
+    "set_cache_rows",
 ]
 
 
@@ -194,6 +197,32 @@ def apply_mrope(cfg: ModelConfig, x, positions3):
     sel = jax.nn.one_hot(idx, 3, dtype=jnp.float32)  # [hd/2, 3]
     ang = jnp.einsum("s...j,js->...j", ang, sel)
     return apply_rope(x, jnp.cos(ang), jnp.sin(ang))
+
+
+# -- decode-cache rows -------------------------------------------------------------
+#
+# A decode cache leaf is [*lead, B, ...]: ``lead`` is the layer axis of a
+# stacked (scanned) block cache, or nothing.  ``at = (*lead_index, row0)``
+# names the rows [row0, row0 + n) of one layer, the rows a decode step of
+# n tokens reads and writes.
+
+
+def _rows_start(buf, at):
+    return tuple(at) + (0,) * (buf.ndim - len(at))
+
+
+def cache_rows(buf, at, n: int):
+    """Rows ``[at[-1], at[-1] + n)`` of the layer ``at[:-1]`` of ``buf``:
+    [n, ...].  Whole rows of an unstacked cache come back unchanged."""
+    lead = len(at) - 1
+    sizes = (1,) * lead + (n,) + buf.shape[len(at):]
+    return jax.lax.dynamic_slice(buf, _rows_start(buf, at), sizes).reshape(sizes[lead:])
+
+
+def set_cache_rows(buf, at, rows):
+    """``buf`` with ``rows`` [n, ...] written where :func:`cache_rows` reads."""
+    rows = rows.astype(buf.dtype).reshape((1,) * (len(at) - 1) + rows.shape)
+    return jax.lax.dynamic_update_slice(buf, rows, _rows_start(buf, at))
 
 
 # -- loss -----------------------------------------------------------------------

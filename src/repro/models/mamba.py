@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import ModelConfig
-from repro.models.layers import rmsnorm
+from repro.models.layers import cache_rows, rmsnorm, set_cache_rows
 
 __all__ = ["mamba_init", "mamba_train", "mamba_prefill", "mamba_decode", "init_ssm_cache"]
 
@@ -134,15 +134,18 @@ def mamba_prefill(p, x, cache, cfg: ModelConfig):
     return jnp.moveaxis(ys, 0, 1), new_cache
 
 
-def mamba_decode(p, x, cache, cfg: ModelConfig):
-    """One-token recurrent step.  x: [B, 1, d] -> (y [B, 1, d], new cache)."""
+def mamba_decode(p, x, cache, cfg: ModelConfig, *, at=(0,)):
+    """One-token recurrent step.  x: [B, 1, d] -> (y [B, 1, d], new cache).
+    ``at`` picks the cache rows x steps, as in ``attention.attn_decode``;
+    their state and conv window are read, then written back whole."""
     B_, _, d = x.shape
     d_in, H, P, N, G = _dims(cfg)
     dt_f = cfg.dtype
     zxbcdt = x[:, 0].astype(dt_f) @ p["in_proj"].astype(dt_f)
     z, xx, Bc, Cc, dtv = _split_proj(cfg, zxbcdt)
     conv_in = jnp.concatenate([xx, Bc, Cc], axis=-1)  # [B, C]
-    window = jnp.concatenate([cache["conv"], conv_in[:, None]], axis=1)  # [B, K, C]
+    conv = cache_rows(cache["conv"], at, B_)
+    window = jnp.concatenate([conv, conv_in[:, None]], axis=1)  # [B, K, C]
     w = p["conv_w"].astype(dt_f)
     conv_out = jax.nn.silu(jnp.einsum("bkc,kc->bc", window, w) + p["conv_b"].astype(dt_f))
     xx, Bc, Cc = jnp.split(conv_out, [d_in, d_in + G * N], axis=-1)
@@ -153,11 +156,14 @@ def mamba_decode(p, x, cache, cfg: ModelConfig):
     Bh = Bc.reshape(B_, G, N).astype(jnp.float32)[:, 0]  # G=1
     Ch = Cc.reshape(B_, G, N).astype(jnp.float32)[:, 0]
     decay = jnp.exp(dtv * A)  # [B,H]
-    state = cache["state"] * decay[..., None, None] + jnp.einsum(
+    state = cache_rows(cache["state"], at, B_) * decay[..., None, None] + jnp.einsum(
         "bh,bhp,bn->bhpn", dtv, xh, Bh
     )
     y = jnp.einsum("bhpn,bn->bhp", state, Ch) + xh * p["D"].astype(jnp.float32)[None, :, None]
     y = y.reshape(B_, 1, d_in).astype(dt_f)
     y = rmsnorm({"scale": p["norm_scale"]}, y * jax.nn.silu(z[:, None]))
     y = y @ p["out_proj"].astype(y.dtype)
-    return y, {"state": state, "conv": window[:, 1:]}
+    return y, {
+        "state": set_cache_rows(cache["state"], at, state),
+        "conv": set_cache_rows(cache["conv"], at, window[:, 1:]),
+    }

@@ -119,7 +119,7 @@ def init_layer(key, cfg: ModelConfig, spec: LayerSpec, cross: bool = False):
 
 
 @jax.named_scope("mlp")
-def _ffn(p, x, cfg: ModelConfig, spec: LayerSpec):
+def _ffn(p, x, cfg: ModelConfig, spec: LayerSpec, capacity_factor: float | None = None):
     """FFN sublayer; returns (delta, aux_losses)."""
     zero = jnp.zeros((), jnp.float32)
     if spec.moe:
@@ -131,7 +131,7 @@ def _ffn(p, x, cfg: ModelConfig, spec: LayerSpec):
             # under 2-D expert sharding
             y, aux = moe_apply_grouped(p["moe"], h, cfg)
             return y, (aux["load_balance"], aux["router_z"])
-        y, aux = moe_apply(p["moe"], h.reshape(B * T, d), cfg)
+        y, aux = moe_apply(p["moe"], h.reshape(B * T, d), cfg, capacity_factor)
         return y.reshape(B, T, d), (aux["load_balance"], aux["router_z"])
     if "mlp" in p:
         return mlp(p["mlp"], norm_apply(p["ln2"], x, cfg), cfg), (zero, zero)
@@ -174,22 +174,29 @@ def init_layer_cache(
 
 
 def apply_layer_decode(
-    p, x, cache, index, cfg: ModelConfig, spec: LayerSpec, *, memory=None,
+    p, x, cache, index, cfg: ModelConfig, spec: LayerSpec, *, memory=None, at=(0,),
 ):
+    """One-token layer step on the cache rows ``at`` picks (see
+    ``attention.attn_decode``); returns (x, the whole new cache).  MoE
+    dispatch is dropless, so a row's token never depends on the rows
+    decoded beside it."""
     h = norm_apply(p["ln1"], x, cfg)
     new_cache = dict(cache)
     if spec.kind == "attn":
         with jax.named_scope("attention"):
-            h, new_kv = attn.attn_decode(p["attn"], h, cache["kv"], index, cfg, window=spec.window)
+            h, new_kv = attn.attn_decode(
+                p["attn"], h, cache["kv"], index, cfg, window=spec.window, at=at
+            )
         new_cache["kv"] = new_kv
     else:
         with jax.named_scope("mamba"):
-            h, new_ssm = mamba_mod.mamba_decode(p["mamba"], h, cache["ssm"], cfg)
+            h, new_ssm = mamba_mod.mamba_decode(p["mamba"], h, cache["ssm"], cfg, at=at)
         new_cache["ssm"] = new_ssm
     x = x + h
     if memory is not None and "xattn" in p:
         x = x + attn.cross_attn(p["xattn"], norm_apply(p["ln_x"], x, cfg), memory, cfg)
-    delta, _ = _ffn(p, x, cfg, spec)
+    dropless = cfg.num_experts / cfg.num_experts_per_tok if spec.moe else None
+    delta, _ = _ffn(p, x, cfg, spec, capacity_factor=dropless)
     return x + delta, new_cache
 
 
@@ -357,29 +364,59 @@ def prefill_with_cache(params, cfg: ModelConfig, cache, tokens=None, embeds=None
     return logits, new_cache
 
 
-def decode_step(params, cfg: ModelConfig, cache, index, tokens=None, embeds=None):
+def decode_step(params, cfg: ModelConfig, cache, index, tokens=None, embeds=None, groups=1):
     """One-token decode.  tokens [B,1] or embeds [B,1,d].  Returns
-    (logits [B,1,V], new_cache)."""
+    (logits [B,1,V], new_cache).
+
+    ``index`` is the new token's position, shared (a scalar) or per row
+    ([B]).  The rows decode as ``groups`` micro-batches of B/groups rows,
+    one after another inside each layer.  The stacked block cache is the
+    state of the one loop over (block, group) steps, and no other loop
+    carries it: each step writes its group's new K/V rows into it in place
+    and reads those rows back, so a donated cache is neither copied nor
+    laid out again.
+    """
     st = structure(cfg)
     x = constrain_hidden(_hidden_from_inputs(params, cfg, tokens, embeds), cfg)
+    B = x.shape[0]
+    if B % groups:
+        raise ValueError(f"{groups} groups do not divide {B} rows")
+    b = B // groups
+    index = jnp.asarray(index, jnp.int32)
+
+    def rows(v, g):  # group g's b rows of v
+        return jax.lax.dynamic_slice_in_dim(v, g * b, b)
+
+    def group_step(ps, specs, x, caches, g, lead):
+        xg = rows(x, g)
+        ig = index if index.ndim == 0 else rows(index, g)
+        caches = list(caches)
+        for j, spec in enumerate(specs):
+            xg, caches[j] = apply_layer_decode(
+                ps[j], xg, caches[j], ig, cfg, spec, at=(*lead, g * b)
+            )
+            xg = constrain_hidden(xg, cfg)
+        return jax.lax.dynamic_update_slice_in_dim(x, xg, g * b, 0), caches
+
     new_prefix = []
     for p, spec, c in zip(params["prefix"], st.prefix, cache["prefix"]):
-        x, nc = apply_layer_decode(p, x, c, index, cfg, spec)
-        x = constrain_hidden(x, cfg)
-        new_prefix.append(nc)
+        for g in range(groups):
+            x, (c,) = group_step([p], [spec], x, [c], g, ())
+        new_prefix.append(c)
     new_cache = {"prefix": new_prefix}
     if st.n_blocks:
-        def block_step(x, scanned):
-            block_params, block_cache = scanned
-            new_bc = []
-            for i, spec in enumerate(st.pattern):
-                x, nc = apply_layer_decode(block_params[i], x, block_cache[i], index, cfg, spec)
-                x = constrain_hidden(x, cfg)
-                new_bc.append(nc)
-            return x, new_bc
+        # one step per (block, group), groups innermost
+        def block_step(carry, step):
+            x, blocks = carry
+            i, g = step // groups, step % groups
+            ps = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), params["blocks"]
+            )
+            return group_step(ps, st.pattern, x, blocks, g, (i,)), None
 
-        x, new_blocks = jax.lax.scan(block_step, x, (params["blocks"], cache["blocks"]))
-        new_cache["blocks"] = new_blocks
+        (x, new_cache["blocks"]), _ = jax.lax.scan(
+            block_step, (x, list(cache["blocks"])), jnp.arange(st.n_blocks * groups)
+        )
     x = norm_apply(params["final_norm"], x, cfg)
     logits = unembed(params["embed"], x, cfg)
     return logits, new_cache

@@ -528,6 +528,11 @@ class PlanRuntime:
 
     # -- inspection -----------------------------------------------------------
 
+    @property
+    def compiled(self):
+        """The current plan's compiled executable (None before a switch)."""
+        return self._compiled
+
     def state_in_flat_layout(self) -> TrainState:
         """The owned state re-stacked to the canonical flat (v=1) layout —
         what checkpoints and cross-kind comparisons consume."""

@@ -2,21 +2,24 @@
 
 The simulated :class:`~repro.serve.runtime.ServeRuntime` prices every tick
 against the trace network; this engine makes the *tokens* real.  It owns the
-model params plus a slot-major decode state (per-slot KV/SSM cache rows,
-per-slot positions, per-slot last token) and runs two kinds of programs:
+model params plus the decode state: one K/V/SSM cache in the decode step's
+own batch layout (``api.init_cache(cfg, max_slots, max_len)``, a row per
+slot), per-slot positions and per-slot last tokens.  It runs two kinds of
+programs:
 
 * **grouped decode tick** — one compiled program per dispatched
   :class:`~repro.core.schedule.TabularPlan`, built by the ``program_factory``
   hook of a *stateless* :class:`~repro.runtime.executor.PlanRuntime`
-  (``optimizer=None``).  The program reshapes the ``max_slots`` slot axis
-  into the plan's ``[M, b]`` micro-batch grid and walks the groups with
-  ``lax.map`` — the executable genuinely depends on the plan, so the tuner's
-  live ``switch_to`` exercises the same ``CompiledStepCache`` warm-switch
-  path training uses.  Per-slot decode positions differ (continuous
-  batching), so the group step is a ``vmap`` of single-slot
-  :func:`repro.models.api.decode_fn` over cache rows and positions.
+  (``optimizer=None``).  The program is one batched
+  :func:`repro.models.api.decode_fn` with ``groups=M``: its single loop
+  walks every (layer, group) step, group g being the plan's b = max_slots/M
+  slots ``[g·b, g·b + b)`` at their own positions (continuous batching),
+  and writes one new K/V token per slot in place.  The cache is donated,
+  so the tick updates it where it lies.  The executable genuinely depends
+  on the plan through M, so the tuner's live ``switch_to`` exercises the
+  same ``CompiledStepCache`` warm-switch path training uses.
 * **fused prefill** — :func:`repro.models.api.prefill_with_cache` on a
-  batch-1 program per prompt length (compiled once per length), scattered
+  batch-1 program per prompt length (compiled once per length), written
   into the admitted slot's cache row.  Prefill is plan-independent: it runs
   before the request joins the grouped grid.
 
@@ -25,7 +28,9 @@ token-for-token; emitted tokens accumulate in ``outputs[rid]``.
 
 Each call opens ``repro.serve.*`` profiler spans (:func:`repro.obs.span`)
 around its host steps, so a trace puts the device's idle time down to them:
-``decode_tick`` carries ``occupied``/``max_slots``/``host_reads``, a
+``decode_tick`` carries ``occupied``/``max_slots``/``host_reads`` and
+``kv_aliased_bytes`` (the cache bytes the decode program aliases from input
+to output; 0 means XLA declined the donation and copies the cache), a
 prefill request ``prompt_len`` and ``new_program`` (1 when its length
 compiles a new prefill program).
 """
@@ -60,16 +65,14 @@ class ServeEngine:
         self.max_slots = max_slots
         self.max_len = max_len
         self.params = api.init_params(jax.random.PRNGKey(init_key), cfg)
-        # slot-major decode state: leaves [max_slots, <batch-1 cache row>...]
-        row = api.init_cache(cfg, 1, max_len)
-        self.kv = jax.tree_util.tree_map(
-            lambda x: jnp.zeros((max_slots,) + x.shape, x.dtype), row
-        )
+        self.kv = api.init_cache(cfg, max_slots, max_len)  # row s is slot s
         self.positions = jnp.zeros((max_slots,), jnp.int32)
         self.tokens = jnp.zeros((max_slots, 1), jnp.int32)
         self.outputs: dict[int, list[int]] = {}
         self._slot_rid: list[int | None] = [None] * max_slots
         self._prefills: dict[int, object] = {}  # prompt length -> jitted prefill
+        self._aliased: dict[tuple, int] = {}  # plan key -> kv_aliased_bytes
+        self.kv_aliased_bytes = 0
         # stateless runtime: no TrainState, programs come from our factory,
         # but the compile cache / warm-switch machinery is the training one
         self.runtime = PlanRuntime(
@@ -92,43 +95,28 @@ class ServeEngine:
             raise ValueError(
                 f"plan {plan.name} needs M={M} | max_slots={self.max_slots}"
             )
-        b = self.max_slots // M
         cfg = self.cfg
 
-        def single(params, cache, pos, tok):
-            logits, nc = api.decode_fn(params, cfg, cache, pos, {"tokens": tok})
-            return logits[:, -1, :], nc  # [1, V]
-
         def step(params, kv, positions, tokens):
-            grid = lambda x: x.reshape((M, b) + x.shape[1:])  # noqa: E731
-            kv_g = jax.tree_util.tree_map(grid, kv)
-            pos_g = positions.reshape(M, b)
-            tok_g = tokens.reshape(M, b, 1, 1)  # per-slot decode_fn sees [1, 1]
-
-            def group(operand):
-                kv_i, pos_i, tok_i = operand
-                logits, nc = jax.vmap(single, in_axes=(None, 0, 0, 0))(
-                    params, kv_i, pos_i, tok_i
-                )
-                return nc, jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-
-            new_kv, new_tok = jax.lax.map(group, (kv_g, pos_g, tok_g))
-            flat = lambda x: x.reshape((self.max_slots,) + x.shape[2:])  # noqa: E731
-            return (
-                jax.tree_util.tree_map(flat, new_kv),
-                new_tok.reshape(self.max_slots, 1),
-            )
+            logits, kv = api.decode_fn(params, cfg, kv, positions, {"tokens": tokens}, groups=M)
+            return kv, jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
 
         spec = lambda t: jax.tree_util.tree_map(  # noqa: E731
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t
         )
         args = (spec(self.params), spec(self.kv), spec(self.positions), spec(self.tokens))
-        return jax.jit(step), args
+        return jax.jit(step, donate_argnums=(1,)), args
 
     # -- ServeRuntime hooks ----------------------------------------------------
 
     def switch_to(self, table):
-        return self.runtime.switch_to(table)
+        event = self.runtime.switch_to(table)
+        key = self.runtime.cache.plan_key(table)
+        if key not in self._aliased:
+            stats = self.runtime.compiled.memory_analysis()
+            self._aliased[key] = int(stats.alias_size_in_bytes) if stats is not None else 0
+        self.kv_aliased_bytes = self._aliased[key]
+        return event
 
     def _prefill_program(self, prompt_len: int):
         """The jitted batch-1 prefill of one prompt length (compiled on its
@@ -167,9 +155,7 @@ class ServeEngine:
                     tok, row = program(self.params, prompt)
                 s = inf.slot
                 with span("repro.serve.prefill.insert"):
-                    self.kv = jax.tree_util.tree_map(
-                        lambda full, r: full.at[s].set(r), self.kv, row
-                    )
+                    self.kv = _set_slot(self.kv, row, s)
                     self.positions = self.positions.at[s].set(req.prompt_len)
                     self.tokens = self.tokens.at[s].set(tok)
                 self._slot_rid[s] = req.rid
@@ -184,6 +170,7 @@ class ServeEngine:
             occupied=len(in_flight),
             max_slots=self.max_slots,
             host_reads=len(in_flight),  # one token read per occupied slot
+            kv_aliased_bytes=self.kv_aliased_bytes,
         ):
             (new_kv, new_tok), _seconds = self.runtime.run_program(
                 self.params, self.kv, self.positions, self.tokens, label="decode"
@@ -197,9 +184,33 @@ class ServeEngine:
                     self.outputs[inf.request.rid].append(int(new_tok[inf.slot, 0]))
                 self.positions = jnp.where(occupied, self.positions + 1, self.positions)
 
+    def slot_cache(self, s: int):
+        """Slot ``s``'s rows of the cache, as a batch-1 cache."""
+        return _slot_rows(self.kv, s)
+
     def release(self, slots) -> None:
         with span("repro.serve.release", slots=len(slots)):
             for s in slots:
                 self._slot_rid[s] = None
                 self.positions = self.positions.at[s].set(0)
                 self.tokens = self.tokens.at[s].set(0)
+
+
+def _slot_rows(kv, s: int):
+    """Slot ``s``'s rows of the cache ``kv`` as a batch-1 cache; a block
+    cache's leaves carry their layer axis before the batch."""
+    take = lambda axis: lambda x: jax.lax.dynamic_slice_in_dim(x, s, 1, axis)  # noqa: E731
+    out = dict(kv, prefix=jax.tree_util.tree_map(take(0), kv["prefix"]))
+    if "blocks" in kv:
+        out["blocks"] = jax.tree_util.tree_map(take(1), kv["blocks"])
+    return out
+
+
+def _set_slot(kv, row, s: int):
+    """The cache ``kv`` with the batch-1 cache ``row`` written where
+    :func:`_slot_rows` reads slot ``s``."""
+    put = lambda axis: lambda x, r: jax.lax.dynamic_update_slice_in_dim(x, r, s, axis)  # noqa: E731
+    out = dict(kv, prefix=jax.tree_util.tree_map(put(0), kv["prefix"], row["prefix"]))
+    if "blocks" in kv:
+        out["blocks"] = jax.tree_util.tree_map(put(1), kv["blocks"], row["blocks"])
+    return out

@@ -109,6 +109,48 @@ def test_windowed_decode_ring_buffer():
     np.testing.assert_allclose(np.asarray(dec), np.asarray(full), atol=1e-4)
 
 
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize(
+    "window,max_len,index",
+    [(None, 12, [0, 5, 11]), (4, 12, [2, 4, 9]), (None, 300, [3, 130, 299])],
+    ids=["full", "ring", "past-one-write-tile"],
+)
+def test_decode_per_row_index_equals_scalar_calls(window, max_len, index, stacked):
+    """``attn_decode`` with a [B] index equals B scalar-index calls, one per
+    row on its own cache (to the round-off of a B-row matmul against a
+    one-row one): the ring buffer at index >= L included, and, in a stacked
+    cache, rows [2, 2 + B) of layer 1 with every other row kept bit for bit."""
+    cfg = _cfg()
+    p = attn_init(jax.random.PRNGKey(8), cfg)
+    B = len(index)
+    x = jax.random.normal(jax.random.PRNGKey(9), (B, 1, cfg.d_model))
+    rows = {
+        n: jax.random.normal(jax.random.PRNGKey(10 + i), c.shape)
+        for i, (n, c) in enumerate(init_kv_cache(cfg, B, max_len, window).items())
+    }
+    if stacked:  # leaves [layers, B + 3, L, K, hd]; x decodes layer 1, rows 2..
+        full = {n: jax.random.normal(jax.random.PRNGKey(20), (2, B + 3) + c.shape[1:])
+                for n, c in rows.items()}
+        cache = {n: full[n].at[1, 2 : 2 + B].set(rows[n]) for n in rows}
+        at = (1, 2)
+    else:
+        cache, at = rows, (0,)
+    decode = jax.jit(lambda x, c, i, at: attn_decode(p, x, c, i, cfg, window=window, at=at))
+    out, new = decode(x, cache, jnp.asarray(index), at)
+    for r, i in enumerate(index):
+        row = {n: c[r : r + 1] for n, c in rows.items()}
+        o, want = decode(x[r : r + 1], row, i, (0,))
+        np.testing.assert_allclose(np.asarray(out[r : r + 1]), np.asarray(o), atol=1e-5)
+        for n in rows:
+            got = new[n][1, 2 + r] if stacked else new[n][r]
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want[n][0]), atol=1e-5)
+    if stacked:
+        for n in rows:
+            kept = np.asarray(new[n]).copy()
+            kept[1, 2 : 2 + B] = np.asarray(full[n][1, 2 : 2 + B])
+            np.testing.assert_array_equal(kept, np.asarray(full[n]))
+
+
 def test_mrope_reduces_to_rope_on_equal_streams():
     """Identical (t, h, w) position streams must equal plain 1-D RoPE."""
     from repro.models.layers import apply_mrope, apply_rope, rope_frequencies

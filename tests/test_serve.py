@@ -304,6 +304,62 @@ def test_prefill_with_cache_matches_token_stepping(arch):
     np.testing.assert_array_equal(np.asarray(nl), np.asarray(rl))
 
 
+@pytest.mark.parametrize(
+    "arch", ["qwen2.5-14b", "jamba-v0.1-52b", "gemma3-12b"]
+)  # dense, attn/ssm hybrid with MoE, windowed attention
+def test_engine_grouped_decode_matches_per_slot_stepping(arch):
+    """``ServeEngine``'s grouped, in-place decode program against each slot
+    stepped alone through the scalar-index ``decode_fn`` on its own batch-1
+    cache: slots at different positions (one past gemma3's 64-token window)
+    and empty ones, a few ticks under M=2 and then M=4."""
+    from repro.core.schedule import make_plan
+    from repro.serve import ServeEngine
+
+    cfg = get_arch(arch).smoke
+    slots, max_len = 8, 80
+    engine = ServeEngine(cfg, 2, max_slots=slots, max_len=max_len)
+    queue, batcher = RequestQueue(), ContinuousBatcher(slots)
+    for rid, n in enumerate((3, 7, 66, 12, 5)):  # slots 5..7 stay empty
+        queue.push(Request(rid, 0.0, n, 8))
+    admitted = batcher.admit(queue, 0.0)
+    engine.prefill(admitted)
+
+    step = jax.jit(lambda p, c, i, t: api.decode_fn(p, cfg, c, i, {"tokens": t}))
+    grouped = jax.jit(
+        lambda p, c, i, t, M: api.decode_fn(p, cfg, c, i, {"tokens": t}, groups=M),
+        static_argnums=4,
+    )
+    refs = [engine.slot_cache(s) for s in range(slots)]
+    for tick, M in enumerate((2, 2, 4, 4)):
+        engine.switch_to(make_plan(2, M, 2, micro_batch_size=slots // M).lower())
+        assert engine.kv_aliased_bytes == sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(engine.kv)
+        )
+        pos, tok = engine.positions, engine.tokens
+        logits, _ = grouped(engine.params, engine.kv, pos, tok, M)
+        engine.decode_tick(batcher.in_flight)
+        for s in range(slots):
+            ref_logits, refs[s] = step(engine.params, refs[s], pos[s], tok[s][None])
+            np.testing.assert_allclose(
+                np.asarray(logits[s], np.float32), np.asarray(ref_logits[0], np.float32),
+                atol=2e-2, rtol=2e-2,
+            )
+            want = int(jnp.argmax(ref_logits[0, -1]))
+            assert int(engine.tokens[s, 0]) == want, (tick, s)
+            # the rows written: equal up to a bf16 rounding of matmuls of b
+            # rows against matmuls of one (a misplaced or missing write is O(1))
+            jax.tree_util.tree_map(
+                lambda a, b: np.testing.assert_allclose(
+                    np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=2**-6, atol=2**-6
+                ),
+                engine.slot_cache(s), refs[s],
+            )
+        for inf in admitted:
+            assert engine.outputs[inf.request.rid][-1] == int(engine.tokens[inf.slot, 0])
+    assert [int(p) for p in engine.positions] == [7, 11, 70, 16, 9, 0, 0, 0]
+    engine.runtime.cache.shutdown()
+
+
 def test_prefill_with_cache_rejects_unsupported_families():
     cfg = get_arch("seamless-m4t-medium").smoke
     with pytest.raises(NotImplementedError):
