@@ -4,8 +4,13 @@ The simulated :class:`~repro.serve.runtime.ServeRuntime` prices every tick
 against the trace network; this engine makes the *tokens* real.  It owns the
 model params plus the decode state: one K/V/SSM cache in the decode step's
 own batch layout (``api.init_cache(cfg, max_slots, max_len)``, a row per
-slot), per-slot positions and per-slot last tokens.  It runs two kinds of
-programs:
+slot), per-slot positions and per-slot last tokens.  The device holds only
+the parameters and the cache: positions (``[max_slots]``) and last tokens
+(``[max_slots, 1]``) are host NumPy arrays, rebound (never written in
+place) whenever they change, so an array a caller holds stays as it was.
+A tick is one ``device_put`` of both, one program and one ``device_get``
+of its new tokens; prefill and release touch them on the host alone.  It
+runs two kinds of programs:
 
 * **grouped decode tick** — one compiled program per dispatched
   :class:`~repro.core.schedule.TabularPlan`, built by the ``program_factory``
@@ -28,7 +33,8 @@ token-for-token; emitted tokens accumulate in ``outputs[rid]``.
 
 Each call opens ``repro.serve.*`` profiler spans (:func:`repro.obs.span`)
 around its host steps, so a trace puts the device's idle time down to them:
-``decode_tick`` carries ``occupied``/``max_slots``/``host_reads`` and
+``decode_tick`` carries ``occupied``/``max_slots``, ``host_reads`` (the
+device→host transfers the tick makes: 1, whatever the occupancy) and
 ``kv_aliased_bytes`` (the cache bytes the decode program aliases from input
 to output; 0 means XLA declined the donation and copies the cache), a
 prefill request ``prompt_len`` and ``new_program`` (1 when its length
@@ -39,6 +45,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import api
 from repro.models.common import ModelConfig
@@ -66,8 +73,8 @@ class ServeEngine:
         self.max_len = max_len
         self.params = api.init_params(jax.random.PRNGKey(init_key), cfg)
         self.kv = api.init_cache(cfg, max_slots, max_len)  # row s is slot s
-        self.positions = jnp.zeros((max_slots,), jnp.int32)
-        self.tokens = jnp.zeros((max_slots, 1), jnp.int32)
+        self.positions = np.zeros((max_slots,), np.int32)
+        self.tokens = np.zeros((max_slots, 1), np.int32)
         self.outputs: dict[int, list[int]] = {}
         self._slot_rid: list[int | None] = [None] * max_slots
         self._prefills: dict[int, object] = {}  # prompt length -> jitted prefill
@@ -156,11 +163,11 @@ class ServeEngine:
                 s = inf.slot
                 with span("repro.serve.prefill.insert"):
                     self.kv = _set_slot(self.kv, row, s)
-                    self.positions = self.positions.at[s].set(req.prompt_len)
-                    self.tokens = self.tokens.at[s].set(tok)
                 self._slot_rid[s] = req.rid
                 with span("repro.serve.prefill.emit"):
-                    self.outputs[req.rid] = [int(tok[0])]
+                    first = int(jax.device_get(tok)[0])
+                    self.outputs[req.rid] = [first]
+                    self._set_host(s, req.prompt_len, first)
 
     def decode_tick(self, in_flight) -> None:
         """One grouped decode step of the CURRENT plan over all slots (empty
@@ -169,20 +176,20 @@ class ServeEngine:
             "repro.serve.decode_tick",
             occupied=len(in_flight),
             max_slots=self.max_slots,
-            host_reads=len(in_flight),  # one token read per occupied slot
+            host_reads=1,  # the tick's one device_get of its new tokens
             kv_aliased_bytes=self.kv_aliased_bytes,
         ):
-            (new_kv, new_tok), _seconds = self.runtime.run_program(
-                self.params, self.kv, self.positions, self.tokens, label="decode"
+            positions, tokens = jax.device_put((self.positions, self.tokens))
+            (self.kv, new_tok), _seconds = self.runtime.run_program(
+                self.params, self.kv, positions, tokens, label="decode"
             )
-            self.kv = new_kv
-            self.tokens = new_tok
             with span("repro.serve.decode.emit"):
-                occupied = jnp.zeros((self.max_slots,), bool)
+                self.tokens = jax.device_get(new_tok)
+                advance = np.zeros((self.max_slots,), np.int32)
                 for inf in in_flight:
-                    occupied = occupied.at[inf.slot].set(True)
-                    self.outputs[inf.request.rid].append(int(new_tok[inf.slot, 0]))
-                self.positions = jnp.where(occupied, self.positions + 1, self.positions)
+                    advance[inf.slot] = 1
+                    self.outputs[inf.request.rid].append(int(self.tokens[inf.slot, 0]))
+                self.positions = self.positions + advance
 
     def slot_cache(self, s: int):
         """Slot ``s``'s rows of the cache, as a batch-1 cache."""
@@ -192,8 +199,14 @@ class ServeEngine:
         with span("repro.serve.release", slots=len(slots)):
             for s in slots:
                 self._slot_rid[s] = None
-                self.positions = self.positions.at[s].set(0)
-                self.tokens = self.tokens.at[s].set(0)
+                self._set_host(s, 0, 0)
+
+    def _set_host(self, s: int, position: int, token: int) -> None:
+        """Slot ``s``'s host position and last token, in fresh arrays."""
+        self.positions = self.positions.copy()
+        self.positions[s] = position
+        self.tokens = self.tokens.copy()
+        self.tokens[s] = token
 
 
 def _slot_rows(kv, s: int):

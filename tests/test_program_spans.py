@@ -121,7 +121,7 @@ def test_serve_spans_nest_and_count(tmp_path):
 
     (tick,) = _named(spans, "repro.serve.decode_tick")
     assert tick[3]["occupied"] == len(batcher.in_flight) == 3
-    assert tick[3]["max_slots"] == 4 and tick[3]["host_reads"] == 3
+    assert tick[3]["max_slots"] == 4 and tick[3]["host_reads"] == 1
     # the decode program updates the donated cache in place: all of it aliased
     cache_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(engine.kv))
     assert tick[3]["kv_aliased_bytes"] == engine.kv_aliased_bytes == cache_bytes > 0

@@ -360,6 +360,45 @@ def test_engine_grouped_decode_matches_per_slot_stepping(arch):
     engine.runtime.cache.shutdown()
 
 
+def test_engine_tick_and_release_make_no_implicit_transfers():
+    """A decode tick moves its per-slot state with one explicit
+    ``device_put`` and one ``device_get``, and ``release`` stays on the
+    host: under ``transfer_guard("disallow")`` an eager per-slot op or an
+    ``int()`` of a device array raises.  The guarded engine serves the
+    tokens an unguarded one serves, and only occupied slots advance."""
+    from repro.core.schedule import make_plan
+    from repro.serve import ServeEngine
+
+    cfg = get_arch("qwen2.5-14b").smoke
+    slots = 6
+
+    def serve(guard: bool):
+        engine = ServeEngine(cfg, 2, max_slots=slots, max_len=32)
+        engine.switch_to(make_plan(2, 2, 2, micro_batch_size=slots // 2).lower())
+        queue, batcher = RequestQueue(), ContinuousBatcher(slots)
+        for rid, n in enumerate((5, 9, 4)):  # slots 3..5 stay empty
+            queue.push(Request(rid, 0.0, n, 8))
+        admitted = batcher.admit(queue, 0.0)
+        engine.prefill(admitted)  # unguarded: the prompt draw is eager
+        occupied = [inf.slot for inf in admitted]
+        with jax.transfer_guard("disallow" if guard else "allow"):
+            for _ in range(2):
+                before = engine.positions
+                engine.decode_tick(batcher.in_flight)
+                assert isinstance(engine.positions, np.ndarray)
+                want = before + np.isin(np.arange(slots), occupied)
+                np.testing.assert_array_equal(engine.positions, want)
+            engine.release([occupied[1]])
+        assert engine.positions[occupied[1]] == 0 and engine.tokens[occupied[1], 0] == 0
+        assert list(engine.positions) == [7, 0, 6, 0, 0, 0]
+        engine.runtime.cache.shutdown()
+        return engine.outputs
+
+    guarded = serve(True)
+    assert all(len(out) == 3 for out in guarded.values())
+    assert guarded == serve(False)
+
+
 def test_prefill_with_cache_rejects_unsupported_families():
     cfg = get_arch("seamless-m4t-medium").smoke
     with pytest.raises(NotImplementedError):
