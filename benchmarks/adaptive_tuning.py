@@ -85,7 +85,7 @@ def run() -> dict:
     for h, rec, est in hours:
         base = est[cands[0].name]  # 1F1B estimate this hour
         rows.append(
-            [f"hour {h}", rec.chosen_k]
+            [f"hour {h}", rec.chosen_spec.k]
             + [f"{est[c.name] / base:.3f}" for c in cands]
         )
     table = markdown_table(
@@ -100,11 +100,11 @@ def run() -> dict:
         assert est[rec.chosen] == best, "tuner must pick its own argmax throughput"
     heavy_hours = [hours[0], hours[1], hours[3]]
     for h, rec, est in heavy_hours:
-        assert rec.chosen_k > 1
+        assert rec.chosen_spec.k > 1
         gain = est[rec.chosen] / est[cands[0].name] - 1
         assert gain > 0.05, f"hour {h}: expected >5% over 1F1B, got {gain:.1%}"
-    eased_k = hours[2][1].chosen_k
-    heavy_ks = [rec.chosen_k for _, rec, _ in heavy_hours]
+    eased_k = hours[2][1].chosen_spec.k
+    heavy_ks = [rec.chosen_spec.k for _, rec, _ in heavy_hours]
     assert eased_k <= min(heavy_ks), "eased hour should need no more grouping"
 
     # run the coordinator through the first hour to confirm realized gains
@@ -123,7 +123,7 @@ def run() -> dict:
     assert realized >= fixed_1f1b
     payload = {
         "hours": [
-            {"hour": h, "chosen_k": rec.chosen_k,
+            {"hour": h, "chosen_k": rec.chosen_spec.k,
              "relative": {c.name: est[c.name] / est[cands[0].name] for c in cands}}
             for h, rec, est in hours
         ],

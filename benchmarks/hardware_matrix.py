@@ -151,12 +151,12 @@ def conformance_slice(spec_path: str, workload_path: str = PINNED_WORKLOAD) -> d
             "estimates": dict(rec.estimates),
             "chosen": {
                 "name": rec.chosen,
-                "kind": rec.chosen_kind,
-                "k": rec.chosen_k,
+                "kind": rec.chosen_spec.kind,
+                "k": rec.chosen_spec.k,
                 "b": chosen.micro_batch_size,
-                "num_virtual": rec.chosen_num_virtual,
-                "extra_warmup": list(rec.chosen_extra_warmup),
-                "zb_policy": list(rec.chosen_zb_policy),
+                "num_virtual": rec.chosen_spec.num_virtual,
+                "extra_warmup": list(rec.chosen_spec.extra_warmup),
+                "zb_policy": list(rec.chosen_spec.zb_policy),
             },
             "makespan_s": {"chosen": makespan_chosen, "one_f1b": makespan_1f1b},
             "makespan_ratio_vs_1f1b": makespan_1f1b / makespan_chosen,

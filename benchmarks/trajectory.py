@@ -308,7 +308,7 @@ def saved_residual_metrics() -> dict:
     rec = AutoTuner(
         cands, lambda _c: w_heavy, NetworkProfiler(uniform_network(S, trace))
     ).tune(0.0)
-    trail = list(rec.chosen_zb_policy)
+    trail = list(rec.chosen_spec.zb_policy)
     mixed = (
         trail
         and trail[0] == "double_remat"
@@ -426,11 +426,11 @@ def tuner_switch_trace() -> dict:
         r = recs[h]
         if r.estimates[r.chosen] < r.estimates[one_f1b]:
             beat += 1
-    kinds = [r.chosen_kind for r in recs]
+    kinds = [r.chosen_spec.kind for r in recs]
     return {
         "tuner_switch_count": switches,
         "tuner_chosen_kinds": kinds,
-        "tuner_chosen_ks": [r.chosen_k for r in recs],
+        "tuner_chosen_ks": [r.chosen_spec.k for r in recs],
         "tuner_kind_diversity": len(set(kinds)),
         "tuner_preempted_hours_beat_1f1b": beat,
         "tuner_candidates": len(cands),

@@ -87,10 +87,10 @@ def main():
     gain = summary.throughput / fixed.throughput - 1
     print(f"adaptive gain: {gain:+.1%}  (paper band: +4%..+30%)")
     assert gain > 0.0
-    assert all(rec.chosen_k > 1 for rec in summary.tuning), (
+    assert all(rec.chosen_spec.k > 1 for rec in summary.tuning), (
         "grouping should win under this cluster's traffic"
     )
-    ks = {rec.chosen_k for rec in summary.tuning}
+    ks = {rec.chosen_spec.k for rec in summary.tuning}
     if len(ks) >= 2:
         print(f"plan switches observed across regimes: k in {sorted(ks)}")
     print("adaptive tuning demo OK")

@@ -2,8 +2,8 @@
 
 Public API re-exports the pieces a user composes:
 
-    plan      = make_plan(S, M, k, b)
-    cands     = enumerate_candidates(S, B, memory_model, limit)
+    plan      = make_plan(S, M, spec=ScheduleSpec(kind, k, ...))
+    cands     = enumerate_candidates(S, B, memory_model, limit, SearchSpace(...))
     tuner     = AutoTuner(cands, stage_costs_for, NetworkProfiler(net))
     summary   = Coordinator(tuner, net, B, interval).run(iters)
 """
@@ -64,10 +64,6 @@ from repro.core.profiler import (
     merge_link_samples,
 )
 from repro.core.schedule import (
-    INTERLEAVED_KINDS,
-    PLAN_KINDS,
-    WARMUP_KINDS,
-    ZB_KINDS,
     Op,
     PlanEdge,
     SchedulePlan,
@@ -130,10 +126,6 @@ __all__ = [
     "NetworkProfiler",
     "merge_link_samples",
     "Op",
-    "PLAN_KINDS",
-    "ZB_KINDS",
-    "INTERLEAVED_KINDS",
-    "WARMUP_KINDS",
     "PlanEdge",
     "SchedulePlan",
     "TabularPlan",

@@ -29,16 +29,14 @@ Duplicated (kind, k, b) never arise (b is a function of (kind, k) on the
 curve), but two k values can map to the same b when memory is
 activation-light; both are kept — they are genuinely different schedules
 with different overlap behaviour.  Schedule kinds beyond kFkB are opt-in
-via the :class:`~repro.core.kinds.SearchSpace` (or the legacy ``kinds=``
-kwarg, which builds one) so the paper's original (k, b)-only search stays
-the default.
+via the :class:`~repro.core.kinds.SearchSpace`, so the paper's original
+(k, b)-only search stays the default.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Sequence
-import warnings
+from typing import Sequence
 
 from repro.core.kinds import (
     ScheduleSpec,
@@ -46,7 +44,6 @@ from repro.core.kinds import (
     admissible_warmup,
     get_kind,
     known_kinds,
-    registered_kinds,
     resolve_alias,
 )
 from repro.core.memory_model import MemoryModel, limit_curve
@@ -104,23 +101,6 @@ def divisors(n: int) -> list[int]:
     return out
 
 
-def _build(
-    plan_factory: Callable[..., SchedulePlan],
-    num_stages: int,
-    M: int,
-    spec: ScheduleSpec,
-) -> SchedulePlan:
-    if (
-        spec.kind in registered_kinds()
-        and get_kind(spec.kind).legacy_factory
-        and spec.num_virtual == 1
-        and not max(spec.extra_warmup)
-    ):
-        # the paper's original search path — keep legacy factories working
-        return plan_factory(num_stages, M, spec.k, micro_batch_size=spec.micro_batch_size)
-    return plan_factory(num_stages, M, spec=spec)
-
-
 def largest_admissible_warmup(
     num_stages: int,
     M: int,
@@ -157,60 +137,24 @@ def enumerate_candidates(
     global_batch: int,
     memory_model: MemoryModel,
     memory_limit_bytes: float | Sequence[float],
-    max_k: int | None = None,
-    min_microbatches: int | None = None,
-    plan_factory: Callable[..., SchedulePlan] = make_plan,
-    kinds: Sequence[str] | None = None,
-    virtual_degrees: Sequence[int] | None = None,
-    max_extra_warmup: int | None = None,
     space: SearchSpace | None = None,
 ) -> list[Candidate]:
-    """Enumerate the memory-limit-curve candidates.
+    """Enumerate the memory-limit-curve candidates of one search space.
 
-    The search axes come from one :class:`~repro.core.kinds.SearchSpace`
-    passed as ``space=``.  The legacy kwargs (``kinds=``,
-    ``virtual_degrees=``, ``max_k=``, ``min_microbatches=``,
-    ``max_extra_warmup=``) are **deprecated** (PR 6 finishes PR 5's
-    migration): they remain accepted — they simply build a ``SearchSpace``,
-    conformance-tested to produce identical candidates — but emit
-    :class:`DeprecationWarning`, and a grep gate keeps in-repo callers on
-    ``space=``.
-
-    ``min_microbatches`` (default: ``num_stages``) rejects plans that
-    cannot even fill the pipeline once — the paper always injects at least
-    one micro-batch per stage.  Per ``(kind, k)`` (times the kind's own
-    extra axes: virtual degree, memory-priced warmup vector — see
-    :meth:`~repro.core.kinds.KindSpec.search_specs`) the largest feasible
-    ``b`` on the limit curve is kept; infeasible combinations (e.g.
-    interleaved divisibility) are skipped silently, unknown kind NAMES
-    fail loudly against the registry.  ``memory_limit_bytes`` may be a
-    scalar or a per-stage curve.
+    The search axes come from one :class:`~repro.core.kinds.SearchSpace`;
+    ``space=None`` is ``SearchSpace()``, the paper's kFkB-only (k, b)
+    search.  ``space.min_microbatches`` (default: ``num_stages``) rejects
+    plans that cannot even fill the pipeline once — the paper always
+    injects at least one micro-batch per stage.  Per ``(kind, k)`` (times
+    the kind's own extra axes: virtual degree, memory-priced warmup vector
+    — see :meth:`~repro.core.kinds.KindSpec.search_specs`) the largest
+    feasible ``b`` on the limit curve is kept; infeasible combinations
+    (e.g. interleaved divisibility) are skipped silently, unknown kind
+    NAMES fail loudly against the registry.  ``memory_limit_bytes`` may be
+    a scalar or a per-stage curve.
     """
-    legacy = {
-        "kinds": kinds,
-        "virtual_degrees": virtual_degrees,
-        "max_k": max_k,
-        "min_microbatches": min_microbatches,
-        "max_extra_warmup": max_extra_warmup,
-    }
-    given = sorted(name for name, value in legacy.items() if value is not None)
-    if given:
-        warnings.warn(
-            f"enumerate_candidates({', '.join(n + '=' for n in given)}...) is "
-            "deprecated; declare the axes as one space=SearchSpace(...)",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        if space is not None:
-            raise ValueError("pass space= or the legacy axis kwargs, not both")
     if space is None:
-        space = SearchSpace(
-            kinds=tuple(kinds) if kinds is not None else ("kfkb",),
-            virtual_degrees=tuple(virtual_degrees) if virtual_degrees is not None else (2,),
-            max_k=max_k,
-            min_microbatches=min_microbatches,
-            max_extra_warmup=max_extra_warmup,
-        )
+        space = SearchSpace()
     min_mb = space.min_microbatches
     if min_mb is None:
         min_mb = num_stages
@@ -257,7 +201,7 @@ def enumerate_candidates(
                         if name != spec.kind:  # alias: let make_plan force k
                             spec = dataclasses.replace(spec, kind=name)
                         try:
-                            plan = _build(plan_factory, num_stages, M, spec)
+                            plan = make_plan(num_stages, M, spec=spec)
                         except ValueError:
                             continue  # e.g. interleaved group-divisibility
                         peaks = memory_model.peak_bytes_per_stage(plan)

@@ -9,8 +9,7 @@ the configured interval it invokes the auto-tuner, applying plan switches
 immediately.
 
 Its two extension points are the typed control-plane protocols of
-:mod:`repro.core.interfaces` (PR 6's api redesign — previously a
-duck-typed ``telemetry=`` object and a bare ``on_iteration`` callable):
+:mod:`repro.core.interfaces`:
 
 * ``telemetry_sink`` — a :class:`~repro.core.interfaces.TelemetrySink`
   (e.g. :class:`repro.runtime.telemetry.TelemetryBus`); every simulated
@@ -21,11 +20,6 @@ duck-typed ``telemetry=`` object and a bare ``on_iteration`` callable):
   :class:`repro.runtime.harness.RealEngineHarness` attaches the live
   plan-switch runtime (compiled-step cache + warm kind switches), closing
   the adaptive loop on real gradients.
-
-The legacy kwargs (``telemetry=`` object, ``on_iteration=`` bare callable)
-still work through shims that emit :class:`DeprecationWarning` and adapt to
-the typed forms; new call sites must use the protocols (a grep gate keeps
-in-repo callers migrated).
 
 One more refinement (default-off, preserving the paper's behaviour): the
 charged ``tuning_overhead`` is scaled by each round's
@@ -39,8 +33,7 @@ This is also the harness the Fig-10 experiment uses.
 from __future__ import annotations
 
 import dataclasses
-import warnings
-from typing import Callable, Sequence
+from typing import Sequence
 
 from repro.core.candidates import Candidate
 from repro.core.interfaces import IterationHook, TelemetrySink
@@ -105,20 +98,6 @@ def shifted_network(net: Network, t0: float) -> Network:
     )
 
 
-_shifted_network = shifted_network  # internal callers predate the public name
-
-
-class _CallableHook:
-    """Adapter giving a bare ``Callable[[IterationRecord], None]`` the
-    :class:`IterationHook` shape (the legacy ``on_iteration=`` shim)."""
-
-    def __init__(self, fn: Callable[[IterationRecord], object]) -> None:
-        self._fn = fn
-
-    def on_iteration(self, rec: IterationRecord) -> object:
-        return self._fn(rec)
-
-
 class Coordinator:
     def __init__(
         self,
@@ -129,7 +108,6 @@ class Coordinator:
         tuning_overhead: float = 0.0,
         hooks: Sequence[IterationHook] = (),
         telemetry_sink: TelemetrySink | None = None,
-        **legacy,
     ) -> None:
         self.tuner = tuner
         self.network = network
@@ -138,36 +116,6 @@ class Coordinator:
         self.tuning_overhead = tuning_overhead
         self.hooks: list[IterationHook] = list(hooks)
         self.telemetry_sink = telemetry_sink
-        # -- legacy shims (PR 6 api redesign) ---------------------------------
-        # telemetry=<duck-typed bus> and on_iteration=<bare callable> predate
-        # the typed protocols; both still work, warn, and adapt.
-        if "telemetry" in legacy:
-            warnings.warn(
-                "Coordinator(telemetry=...) is deprecated; pass the typed "
-                "telemetry_sink= (any repro.core.interfaces.TelemetrySink)",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-            shimmed = legacy.pop("telemetry")
-            if shimmed is not None:
-                if self.telemetry_sink is not None:
-                    raise ValueError("pass telemetry_sink= or telemetry=, not both")
-                self.telemetry_sink = shimmed
-        if "on_iteration" in legacy:
-            warnings.warn(
-                "Coordinator(on_iteration=<callable>) is deprecated; pass "
-                "hooks=[...] of repro.core.interfaces.IterationHook "
-                "participants (objects with an on_iteration method)",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-            fn = legacy.pop("on_iteration")
-            if fn is not None:
-                self.hooks.append(
-                    fn if isinstance(fn, IterationHook) else _CallableHook(fn)
-                )
-        if legacy:
-            raise TypeError(f"unknown Coordinator kwargs: {sorted(legacy)}")
 
     def run(self, num_iterations: int, tune_first: bool = True) -> RunSummary:
         now = 0.0
@@ -186,7 +134,7 @@ class Coordinator:
                 next_tune = now + self.tuning_interval
             cand: Candidate = self.tuner.current
             costs = self.tuner.stage_costs_for(cand)
-            result = simulate_plan(cand.plan, costs, _shifted_network(self.network, now))
+            result = simulate_plan(cand.plan, costs, shifted_network(self.network, now))
             rec = IterationRecord(
                 index=i,
                 start=now,

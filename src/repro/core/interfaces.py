@@ -1,12 +1,9 @@
 """Typed control-plane interfaces: the contracts core exposes to runtimes.
 
-Until PR 6 the coordinator's two extension points were duck-typed — a bare
-``telemetry=`` object "with ``publish_iteration``" and an untyped
-``on_iteration`` callable — which worked for one in-process harness but
-made the cross-host fabric impossible to reason about: a wire protocol
-needs *named* contracts.  This module states them as structural
-:class:`typing.Protocol`\\ s, so implementations register by shape, not by
-import (``core`` still never imports ``repro.runtime``):
+The cross-host fabric's wire protocol needs *named* contracts, so this
+module states the coordinator's two extension points as structural
+:class:`typing.Protocol`\\ s: implementations register by shape, not by
+import (``core`` never imports ``repro.runtime``):
 
 * :class:`TelemetrySink` — anything that accepts per-iteration timing
   observations.  Implemented by
@@ -14,16 +11,14 @@ import (``core`` still never imports ``repro.runtime``):
   by the fabric's :class:`~repro.runtime.fabric.worker.WorkerAgent`
   window buffer (cross-host batching).
 * :class:`IterationHook` — a participant that reacts to each coordinator
-  iteration *by method* (``on_iteration(rec)``), replacing the bare
-  callable.  Implemented by
+  iteration *by method* (``on_iteration(rec)``).  Implemented by
   :class:`repro.runtime.harness.RealEngineHarness`; the method form is
   what lets the fabric treat hooks and switch participants uniformly.
 
 The :class:`~repro.core.coordinator.Coordinator` consumes both via its
-``telemetry_sink=`` / ``hooks=`` parameters; the legacy ``telemetry=`` /
-``on_iteration=`` kwargs survive as :class:`DeprecationWarning` shims (see
-its docstring).  The transport-level protocols of the fabric itself
-(``ControlTransport``, ``SwitchParticipant``) live with the fabric in
+``telemetry_sink=`` / ``hooks=`` parameters.  The transport-level
+protocols of the fabric itself (``ControlTransport``,
+``SwitchParticipant``) live with the fabric in
 :mod:`repro.runtime.fabric.protocols` — they are wire contracts, not core
 contracts — and re-export these two so the whole control plane is
 importable from one place.
@@ -69,8 +64,7 @@ class TelemetrySink(Protocol):
 class IterationHook(Protocol):
     """Reacts to one completed coordinator iteration.
 
-    The method form (vs the deprecated bare callable) is deliberate: a
-    hook is an *agent* with its own state — the real-engine harness, a
+    The method form is deliberate: a hook is an *agent* with its own state — the real-engine harness, a
     fabric worker — and the named method is what the fabric's
     ``SwitchParticipant`` protocol extends.
     """

@@ -196,9 +196,6 @@ class KindSpec:
     weight_placement_refinable: bool = False
     peak_is_exact: bool = False
     needs_group_multiple_of_stages: bool = False
-    # the paper's original (k, b)-only search family: plans may be built
-    # through legacy positional plan factories (no kind/virtual/warmup kwargs)
-    legacy_factory: bool = False
     virtual_stage: Callable[[int, int, int, int], int] | None = None
     label: Callable[[str, int, str, int], str] | None = None
     search_specs_fn: Callable[..., list[ScheduleSpec]] | None = None
@@ -328,7 +325,7 @@ def get_kind(kind: str) -> KindSpec:
 
 
 def registered_kinds() -> tuple[str, ...]:
-    """All registered kinds, in registration order (``PLAN_KINDS`` view)."""
+    """All registered kinds, in registration order."""
     return tuple(_REGISTRY)
 
 
@@ -492,7 +489,6 @@ register_kind(
         build_orders=_kfkb_build,
         peak_live_groups=_peak_1f1b,
         peak_is_exact=True,
-        legacy_factory=True,
     )
 )
 register_kind(

@@ -15,8 +15,9 @@ How to add a schedule kind
 Kinds are pluggable: everything the system knows about one family member
 lives in a single :class:`repro.core.kinds.KindSpec` record, and NOTHING
 outside ``repro/core/kinds.py`` (and this module's generic machinery) may
-dispatch on the kind string — a CI grep gate and the tier-1 coverage gates
-enforce it.  A new member needs exactly:
+dispatch on the kind string — a CI grep gate and
+``test_no_kind_string_dispatch_outside_registry`` enforce it.  A new
+member needs exactly:
 
 1. a ``register_kind(KindSpec(...))`` call in ``repro/core/kinds.py``
    providing (a) ``build_orders`` — per-device ordered :class:`Task` lists,
@@ -43,11 +44,15 @@ enforce it.  A new member needs exactly:
 
 Everything else — lowering, slot assignment, the simulator, the memory
 model, candidate enumeration, the tuner, both engines, viz — is
-kind-agnostic and picks the new member up through the registry.
+kind-agnostic and picks the new member up through the registry.  Callers
+name a member by its coordinates, ``make_plan(S, M,
+spec=ScheduleSpec(kind=..., k=..., num_virtual=..., extra_warmup=...,
+zb_policy=...))``; the registry's flags (``registered_kinds()``,
+``get_kind(kind).<flag>``) answer which kinds support what.
 
-Schedule-family matrix (``make_plan(..., kind=...)`` or
-``make_plan(..., spec=ScheduleSpec(...))``).  ``w[s]`` is the per-stage
-extra-warmup vector (``extra_warmup``: a scalar broadcasts, a sequence
+Schedule-family matrix (the ``ScheduleSpec`` coordinates per kind).
+``w[s]`` is the per-stage extra-warmup vector (``extra_warmup``: a
+scalar broadcasts, a sequence
 gives each stage its own depth — sized to ITS memory headroom on the
 per-stage limit curve).  ``zb_policy[s]`` is the per-stage BWD_WEIGHT
 policy for split-backward kinds (``zb_policy``: a scalar broadcasts):
@@ -162,7 +167,6 @@ from __future__ import annotations
 import dataclasses
 import enum
 from typing import Iterator, Sequence
-import warnings
 
 import numpy as np
 
@@ -174,10 +178,6 @@ __all__ = [
     "SchedulePlan",
     "TabularPlan",
     "PlanEdge",
-    "PLAN_KINDS",
-    "ZB_KINDS",
-    "INTERLEAVED_KINDS",
-    "WARMUP_KINDS",
     "one_f_one_b_order",
     "gpipe_order",
     "kfkb_order",
@@ -206,35 +206,6 @@ class Op(enum.IntEnum):
 
 #: ops that consume a cross-stage input produced by the NEXT virtual stage
 _BWD_CRITICAL = (Op.BWD, Op.BWD_INPUT)
-
-
-def __getattr__(name: str):
-    """Legacy kind-set views, derived live from the registry (PEP 562).
-
-    ``PLAN_KINDS`` / ``ZB_KINDS`` / ``INTERLEAVED_KINDS`` / ``WARMUP_KINDS``
-    used to be hand-maintained literal tuples that every new kind had to
-    edit; they are now computed from :mod:`repro.core.kinds`, so a
-    registered kind is a member of exactly the sets its capability flags
-    claim.  Prefer the registry (``get_kind(kind).<flag>``) in new code —
-    these exist so pre-registry call sites and tests keep working
-    unchanged.
-    """
-    if name in ("PLAN_KINDS", "ZB_KINDS", "INTERLEAVED_KINDS", "WARMUP_KINDS"):
-        from repro.core import kinds as _kinds
-
-        registry = [_kinds.get_kind(k) for k in _kinds.registered_kinds()]
-        if name == "PLAN_KINDS":
-            return tuple(s.name for s in registry)
-        if name == "ZB_KINDS":
-            return tuple(s.name for s in registry if s.has_split_backward)
-        if name == "INTERLEAVED_KINDS":
-            return tuple(
-                s.name
-                for s in registry
-                if s.supports_virtual or s.fixed_virtual is not None
-            )
-        return tuple(s.name for s in registry if s.supports_extra_warmup)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def normalize_warmup(extra_warmup: int | Sequence[int], num_stages: int) -> tuple[int, ...]:
@@ -858,56 +829,27 @@ def make_plan(
     k: int | None = None,
     micro_batch_size: int = 1,
     name: str = "",
-    kind: str = "kfkb",
-    num_virtual: int = 1,
-    extra_warmup: int | Sequence[int] = 0,
     spec=None,
 ) -> SchedulePlan:
     """Build a validated :class:`SchedulePlan` of any registered family member.
 
     The schedule coordinates come from one
-    :class:`~repro.core.kinds.ScheduleSpec` via ``spec=`` (the system's one
-    coordinate currency), or — for the paper's original two-coordinate
-    search — the plain positional ``(k, micro_batch_size)`` form.  The
-    family kwargs ``kind=`` / ``num_virtual=`` / ``extra_warmup=`` are
-    **deprecated** (PR 5 grew ``ScheduleSpec`` to carry them; PR 6 finishes
-    the migration): they still lower to identical plans
-    (conformance-tested) but emit :class:`DeprecationWarning`, and a grep
-    gate keeps in-repo callers on ``spec=``.  ``kind`` must be registered
-    in :mod:`repro.core.kinds` (``"1f1b"`` and ``"gpipe"`` are aliases
-    that force ``k``); coordinate validation — virtual-degree rules,
-    warmup capability, H2's ``w >= 1`` floor — is
-    ``ScheduleSpec.resolve``'s, driven by the kind's capability flags.
+    :class:`~repro.core.kinds.ScheduleSpec` via ``spec=``.  The positional
+    ``(k, micro_batch_size)`` form is the paper's kFkB shorthand for
+    ``spec=ScheduleSpec(kind="kfkb", k=k, micro_batch_size=micro_batch_size)``
+    (``k`` defaults to 1); passing either together with ``spec=`` is a
+    ``ValueError``.  The spec's kind must be registered in
+    :mod:`repro.core.kinds` (``"1f1b"`` and ``"gpipe"`` are aliases that
+    force ``k``); coordinate validation — virtual-degree rules, warmup
+    capability, H2's ``w >= 1`` floor — is ``ScheduleSpec.resolve``'s,
+    driven by the kind's capability flags.
     """
     from repro.core.kinds import ScheduleSpec, get_kind
 
-    if spec is not None:
-        if k is not None or kind != "kfkb" or num_virtual != 1 or extra_warmup:
-            raise ValueError("pass either spec= or the legacy schedule kwargs, not both")
-        if micro_batch_size != 1:
-            raise ValueError("micro_batch_size travels inside spec= when given")
-    else:
-        w_max = (
-            extra_warmup
-            if isinstance(extra_warmup, int)
-            else max(extra_warmup, default=0)
-        )
-        if kind != "kfkb" or num_virtual != 1 or w_max:
-            warnings.warn(
-                "make_plan(kind=..., num_virtual=..., extra_warmup=...) is "
-                "deprecated; pass the coordinates as one "
-                "spec=ScheduleSpec(kind=..., k=..., num_virtual=..., "
-                "extra_warmup=..., micro_batch_size=...)",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-        spec = ScheduleSpec(
-            kind=kind,
-            k=1 if k is None else k,
-            num_virtual=num_virtual,
-            extra_warmup=extra_warmup,
-            micro_batch_size=micro_batch_size,
-        )
+    if spec is None:
+        spec = ScheduleSpec(k=1 if k is None else k, micro_batch_size=micro_batch_size)
+    elif k is not None or micro_batch_size != 1:
+        raise ValueError("pass either spec= or the positional (k, micro_batch_size), not both")
     spec = spec.resolve(num_stages, num_microbatches)
     kspec = get_kind(spec.kind)
     orders = kspec.build_orders(

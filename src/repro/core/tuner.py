@@ -60,21 +60,11 @@ class TuningRecord:
     time: float
     estimates: dict[str, float]  # candidate name -> estimated pipeline length
     chosen: str
-    chosen_k: int
+    # the winner's normalized schedule coordinates — the same ScheduleSpec
+    # the candidate, the compile-cache key and the runtime consume (its
+    # per-stage extra_warmup / zb_policy vectors are the tuner's trail)
+    chosen_spec: ScheduleSpec
     switched: bool
-    chosen_kind: str = "kfkb"
-    chosen_num_virtual: int = 1
-    # the winner's per-stage warmup vector w[s]; all-zero unless a warmup
-    # kind (zb_h2 / warmed interleaved_zb) won
-    chosen_extra_warmup: tuple[int, ...] = ()
-    # the winner's per-stage BWD_WEIGHT policy vector (split-backward kinds:
-    # "saved_residual" on stages whose limit admitted the residuals,
-    # "double_remat" elsewhere) — the tuner's policy trail
-    chosen_zb_policy: tuple[str, ...] = ()
-    # the winner's full schedule coordinates — the same ScheduleSpec the
-    # candidate, the compile-cache key and the runtime consume (the legacy
-    # chosen_* fields above are its projections, kept for callers)
-    chosen_spec: ScheduleSpec | None = None
     # suspend-and-probe accounting for this round: with passive telemetry
     # keeping the profiler windows fresh, probes_run drops toward 0 and the
     # coordinator scales the charged tuning_overhead accordingly
@@ -240,13 +230,8 @@ class AutoTuner:
             time=now,
             estimates=estimates,
             chosen=best.name,
-            chosen_k=best.k,
-            switched=switched,
-            chosen_kind=best.plan.kind,
-            chosen_num_virtual=best.plan.num_virtual,
-            chosen_extra_warmup=best.plan.extra_warmup,
-            chosen_zb_policy=tuple(best.plan.zb_policy),
             chosen_spec=best.spec,
+            switched=switched,
             probes_run=self._probes_run,
             probes_skipped=self._probes_skipped,
             # losers ranked by the deciding scores (SLO-weighted when an

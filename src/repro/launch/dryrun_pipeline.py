@@ -103,11 +103,11 @@ def _tune_on_spec(cal, spec, S: int, b_mb: int) -> dict:
         "estimates": rec.estimates,
         "chosen": {
             "name": rec.chosen,
-            "kind": rec.chosen_kind,
-            "k": rec.chosen_k,
+            "kind": rec.chosen_spec.kind,
+            "k": rec.chosen_spec.k,
             "b": chosen.micro_batch_size,
-            "extra_warmup": list(rec.chosen_extra_warmup),
-            "zb_policy": list(rec.chosen_zb_policy),
+            "extra_warmup": list(rec.chosen_spec.extra_warmup),
+            "zb_policy": list(rec.chosen_spec.zb_policy),
         },
     }
 

@@ -262,7 +262,7 @@ def chosen_specs_by_regime(max_requests: int = 40, seed: int = 0) -> dict:
             "trail": trail,
             "final_spec": (
                 dataclasses.asdict(sc.tuner.history[-1].chosen_spec)
-                if sc.tuner.history and sc.tuner.history[-1].chosen_spec
+                if sc.tuner.history
                 else None
             ),
         }

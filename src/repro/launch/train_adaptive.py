@@ -378,7 +378,7 @@ def summarize(sc: Fig10Scenario, summary) -> dict:
         "iterations": len(rt.iterations),
         "losses": [round(r.loss, 4) for r in rt.iterations],
         "decision_trail": [
-            {"t": round(r.time, 1), "chosen": r.chosen, "kind": r.chosen_kind}
+            {"t": round(r.time, 1), "chosen": r.chosen, "kind": r.chosen_spec.kind}
             for r in summary.tuning
         ],
         "kind_switches": sc.harness.kind_switches,

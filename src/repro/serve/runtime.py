@@ -222,8 +222,8 @@ class ServeRuntime:
                         f"{self.track}/tuner",
                         f"decision {rec.chosen}",
                         self.now,
-                        kind=rec.chosen_kind,
-                        k=rec.chosen_k,
+                        kind=rec.chosen_spec.kind,
+                        k=rec.chosen_spec.k,
                         queue=len(self.queue),
                     )
             cand = self.tuner.current
@@ -280,13 +280,13 @@ class ServeRuntime:
                     {
                         "t": round(r.time, 3),
                         "chosen": r.chosen,
-                        "kind": r.chosen_kind,
-                        "k": r.chosen_k,
+                        "kind": r.chosen_spec.kind,
+                        "k": r.chosen_spec.k,
                     }
                     for r in self.tuner.history
                 ],
                 "kinds_chosen": sorted(
-                    {r.chosen_kind for r in self.tuner.history}
+                    {r.chosen_spec.kind for r in self.tuner.history}
                 ),
                 "tokens_per_second": (
                     out["tokens"] / self.now if self.now else 0.0

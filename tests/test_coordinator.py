@@ -19,6 +19,7 @@ from repro.core import (
     StableTrace,
     StageCosts,
     make_plan,
+    shifted_network,
     simulate_plan,
     uniform_network,
 )
@@ -26,7 +27,6 @@ from repro.core import coordinator
 from repro.core.network import Network
 
 _ShiftedTrace = coordinator._ShiftedTrace
-_shifted_network = coordinator._shifted_network
 
 
 def _costs_for(S=4):
@@ -90,8 +90,8 @@ def test_coordinator_iterations_see_the_shifted_world():
     plan = make_plan(S, M, 1)
 
     # the trace is genuinely phase-sensitive at this workload
-    l0 = simulate_plan(plan, costs, _shifted_network(net, 0.0)).pipeline_length
-    l_mid = simulate_plan(plan, costs, _shifted_network(net, 8.0)).pipeline_length
+    l0 = simulate_plan(plan, costs, shifted_network(net, 0.0)).pipeline_length
+    l_mid = simulate_plan(plan, costs, shifted_network(net, 8.0)).pipeline_length
     assert l0 != pytest.approx(l_mid)
 
     cand = Candidate(1, 1, M, plan, 0.0)
@@ -102,7 +102,7 @@ def test_coordinator_iterations_see_the_shifted_world():
     for rec in summary.iterations:
         assert rec.start == pytest.approx(now)
         expected = simulate_plan(
-            plan, costs, _shifted_network(net, rec.start)
+            plan, costs, shifted_network(net, rec.start)
         ).pipeline_length
         assert rec.length == pytest.approx(expected)
         now += rec.length
@@ -113,44 +113,6 @@ def test_run_summary_throughput_zero_time_edge():
     assert empty.throughput == 0.0  # no division by zero
     some = RunSummary(iterations=[], tuning=[], total_time=2.0, total_samples=8)
     assert some.throughput == pytest.approx(4.0)
-
-
-def test_legacy_coordinator_kwargs_warn_and_still_work():
-    """PR 6 typed-hook migration: ``telemetry=`` and ``on_iteration=`` are
-    deprecated shims — they warn, but route to ``telemetry_sink=`` /
-    ``hooks=`` so external callers keep working for one release."""
-    S = 4
-    net = uniform_network(S, lambda: StableTrace(2.0))
-
-    def coord(**kw):
-        tuner = AutoTuner(_cands(S), _costs_for(S), NetworkProfiler(net))
-        return Coordinator(tuner, net, global_batch=8, tuning_interval=1e9, **kw)
-
-    seen = []
-    with pytest.warns(DeprecationWarning, match="hooks="):
-        c = coord(on_iteration=seen.append)
-    c.run(2)
-    assert len(seen) == 2  # the wrapped callable still fires per iteration
-
-    class Sink:
-        def __init__(self):
-            self.n = 0
-
-        def publish_iteration(self, **kw):
-            self.n += 1
-
-    sink = Sink()
-    with pytest.warns(DeprecationWarning, match="telemetry_sink="):
-        c = coord(telemetry=sink)
-    assert c.telemetry_sink is sink
-    c.run(2)
-    assert sink.n == 2
-
-    with pytest.warns(DeprecationWarning):
-        with pytest.raises(ValueError, match="not both"):
-            coord(telemetry=sink, telemetry_sink=sink)
-    with pytest.raises(TypeError, match="unknown Coordinator kwargs"):
-        coord(bogus_kwarg=1)
 
 
 def test_typed_hooks_receive_iteration_records():

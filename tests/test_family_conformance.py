@@ -34,7 +34,6 @@ from repro.core import predicted_peak_live
 from repro.core.kinds import ScheduleSpec, get_kind, registered_kinds, warmup_kinds
 from repro.core.network import StableTrace, uniform_network
 from repro.core.schedule import (
-    PLAN_KINDS,
     Op,
     make_plan,
     normalize_warmup,
@@ -233,10 +232,8 @@ def test_family_conformance(cell):
 def test_grid_covers_every_plan_kind():
     """Tier-1 gate, auto-derived from the REGISTRY: every registered kind
     must contribute conformance cells — adding a kind without grid
-    coverage fails here before it can ship (and the legacy PLAN_KINDS view
-    must agree with the registry)."""
+    coverage fails here before it can ship."""
     assert {c[0] for c in CELLS} == set(registered_kinds())
-    assert tuple(PLAN_KINDS) == registered_kinds()
 
 
 def test_grid_covers_vector_warmup():
@@ -249,7 +246,7 @@ def test_grid_covers_vector_warmup():
 
 
 @given(
-    st.sampled_from(PLAN_KINDS),
+    st.sampled_from(registered_kinds()),
     st.integers(0, 2).map(lambda e: 2**e),  # k
     st.integers(2, 3),  # v (interleaved kinds only)
     st.lists(st.integers(0, 3), min_size=5, max_size=5),  # w[s] (warmup kinds)

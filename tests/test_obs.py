@@ -7,7 +7,7 @@ Six suites:
   order, Chrome trace-event schema round-trip, nesting/overlap validators,
   and the headline determinism property: under an injected tick clock two
   recordings of the same event sequence export **byte-identical** JSON;
-* :func:`render_simulated_trace` — the PLAN_KINDS gate: every registered
+* :func:`render_simulated_trace` — the registered-kinds gate: every registered
   schedule kind's simulated timeline must render with pairwise-disjoint
   spans on every device and link track (an overlap is a renderer or
   simulator bug), plus the committed golden fixture staying bit-for-bit
@@ -184,7 +184,7 @@ def test_save_writes_loadable_json(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# render_simulated_trace: the PLAN_KINDS no-overlap gate + golden fixture
+# render_simulated_trace: the registered-kinds no-overlap gate + golden fixture
 # ---------------------------------------------------------------------------
 
 
@@ -203,11 +203,13 @@ def test_every_plan_kind_renders_without_overlap():
     """Tier-1 gate: each registered kind's simulated timeline must be a
     legal schedule rendering — pairwise-disjoint spans on every device and
     link track, and the last span ending exactly at the simulated makespan."""
-    from repro.core import PLAN_KINDS, StableTrace, StageCosts, make_plan, uniform_network
+    from repro.core import (
+        StableTrace, StageCosts, make_plan, registered_kinds, uniform_network,
+    )
 
     S, M = 4, 8
     costs = StageCosts.uniform(S, 1.0, act_bytes=1.0)
-    for kind in PLAN_KINDS:
+    for kind in registered_kinds():
         plan = make_plan(S, M, spec=_spec_for(kind))
         rec, result = render_simulated_trace(
             plan, costs, uniform_network(S, lambda: StableTrace(2.0))
@@ -468,10 +470,11 @@ def test_fabric_metrics_dict_shape_frozen_over_registry():
 
 
 def test_tuning_record_rejected_candidates_back_compat():
+    from repro.core import ScheduleSpec
     from repro.core.tuner import AutoTuner, TuningRecord
 
     rec = TuningRecord(time=0.0, estimates={"a": 1.0}, chosen="a",
-                       chosen_k=1, switched=False)
+                       chosen_spec=ScheduleSpec(), switched=False)
     assert rec.rejected_candidates == ()  # pre-PR-9 construction still valid
 
     rejections = AutoTuner._rejections(

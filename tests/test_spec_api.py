@@ -1,23 +1,20 @@
-"""The ScheduleSpec / SearchSpace / kind-registry API redesign, proven.
+"""The ScheduleSpec / SearchSpace / kind-registry API, proven.
 
-Five suites:
+Four suites:
 
-* **Back-compat conformance** — the legacy ``make_plan(**kwargs)`` /
-  ``enumerate_candidates(kinds=..., virtual_degrees=...)`` signatures and
-  the new ``spec=`` / ``space=`` forms produce IDENTICAL plans (same
-  lowered ``TabularPlan`` digests) and identical candidate sets.  This
-  file is the ONE place legacy forms are called on purpose (module-level
-  ``filterwarnings`` below); everywhere else the gate test bites.
-* **Deprecation contract** — the legacy forms warn ``DeprecationWarning``
-  (PR 6), the modern forms stay silent, and mixing both is a loud error.
+* **One coordinate currency** — every plan is built from one
+  ``ScheduleSpec``; ``plan.spec`` comes back normalized (aliases folded,
+  per-stage ``w`` and ``zb_policy`` vectors) and rebuilding from it gives
+  the same lowered ``TabularPlan`` digest.  The paper's positional
+  ``make_plan(S, M, k, micro_batch_size=b)`` is bit-identical to its kfkb
+  ``spec=``, and ``enumerate_candidates`` without ``space=`` is the
+  paper's kfkb-only search.
 * **Fail-closed registry** — an unregistered kind is a loud ``ValueError``
   naming the registered kinds, everywhere a kind string enters the system.
-* **No string dispatch / no legacy call forms** — the tier-1 gates: no
-  module under ``src/repro`` outside ``core/kinds.py`` /
-  ``core/schedule.py`` may dispatch on schedule-kind strings, and no
-  in-repo caller outside this file may use the deprecated kwarg forms or
-  the untyped Coordinator hooks (the CI lint job runs the same scans;
-  these tests make them bite locally).
+* **No string dispatch** — the tier-1 gate: no module under
+  ``src/repro`` outside ``core/kinds.py`` / ``core/schedule.py`` may
+  dispatch on schedule-kind strings (the CI lint job runs the same scan;
+  this test makes it bite locally).
 * **ZB-V acceptance** — the first registry-only family member shows the
   controllable-memory trade: peak live strictly below the equal-(S, M, k)
   plain-interleaved plan's, makespan no worse than 1F1B on the preemption
@@ -25,17 +22,12 @@ Five suites:
   the compile-cache key through the one ScheduleSpec currency.
 """
 
-import ast
 import hashlib
 import os
 import re
 import warnings as _warnings
 
 import pytest
-
-# the conformance suite exercises the deprecated forms BY DESIGN; the
-# explicit deprecation tests below re-enable the filter locally
-pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
 from repro.core import (
     MemoryModel,
@@ -77,82 +69,94 @@ def _mm(S):
 
 
 # ---------------------------------------------------------------------------
-# Back-compat conformance: legacy kwargs == ScheduleSpec / SearchSpace
+# One coordinate currency: ScheduleSpec in, normalized ScheduleSpec out
 # ---------------------------------------------------------------------------
 
-_LEGACY_VS_SPEC = [
-    (dict(k=1), ScheduleSpec()),
-    (dict(k=2, micro_batch_size=2), ScheduleSpec(k=2, micro_batch_size=2)),
-    (dict(k=1, kind="1f1b"), ScheduleSpec(kind="1f1b")),
-    (dict(k=1, kind="gpipe"), ScheduleSpec(kind="gpipe")),
-    (dict(k=2, kind="zb_h1"), ScheduleSpec(kind="zb_h1", k=2)),
-    (dict(k=1, kind="zb_h2", extra_warmup=2),
-     ScheduleSpec(kind="zb_h2", extra_warmup=2)),
-    (dict(k=1, kind="zb_h2", extra_warmup=(0, 1, 0, 2)),
-     ScheduleSpec(kind="zb_h2", extra_warmup=(0, 1, 0, 2))),
-    (dict(k=2, kind="interleaved", num_virtual=2),
-     ScheduleSpec(kind="interleaved", k=2, num_virtual=2)),
-    (dict(k=1, kind="interleaved_zb", num_virtual=2, extra_warmup=1),
-     ScheduleSpec(kind="interleaved_zb", num_virtual=2, extra_warmup=1)),
-    (dict(k=1, kind="zbv"), ScheduleSpec(kind="zbv")),
-    (dict(k=2, kind="zbv", extra_warmup=(1, 0, 2, 1)),
-     ScheduleSpec(kind="zbv", k=2, extra_warmup=(1, 0, 2, 1))),
+_S, _M = 4, 8
+_W0, _DR = (0,) * _S, ("double_remat",) * _S
+
+#: (spec, its normalized form at S=4, M=8) — one per family coordinate
+_SPECS = [
+    (ScheduleSpec(), ScheduleSpec("kfkb", 1, 1, _W0, 1, _DR)),
+    (ScheduleSpec(k=2, micro_batch_size=2), ScheduleSpec("kfkb", 2, 1, _W0, 2, _DR)),
+    (ScheduleSpec(kind="1f1b"), ScheduleSpec("kfkb", 1, 1, _W0, 1, _DR)),
+    (ScheduleSpec(kind="gpipe"), ScheduleSpec("kfkb", _M, 1, _W0, 1, _DR)),
+    (ScheduleSpec(kind="zb_h1", k=2), ScheduleSpec("zb_h1", 2, 1, _W0, 1, _DR)),
+    (ScheduleSpec(kind="zb_h2", extra_warmup=2),
+     ScheduleSpec("zb_h2", 1, 1, (2,) * _S, 1, _DR)),
+    (ScheduleSpec(kind="zb_h2", extra_warmup=(0, 1, 0, 2)),
+     ScheduleSpec("zb_h2", 1, 1, (0, 1, 0, 2), 1, _DR)),
+    (ScheduleSpec(kind="interleaved", k=2, num_virtual=2),
+     ScheduleSpec("interleaved", 2, 2, _W0, 1, _DR)),
+    (ScheduleSpec(kind="interleaved_zb", num_virtual=2, extra_warmup=1),
+     ScheduleSpec("interleaved_zb", 1, 2, (1,) * _S, 1, _DR)),
+    (ScheduleSpec(kind="zbv"), ScheduleSpec("zbv", 1, 2, _W0, 1, _DR)),
+    (ScheduleSpec(kind="zbv", k=2, extra_warmup=(1, 0, 2, 1)),
+     ScheduleSpec("zbv", 2, 2, (1, 0, 2, 1), 1, _DR)),
 ]
+_ROUNDTRIP_IDS = [s.kind + f"-k{s.k}" for s, _ in _SPECS]
+#: alias folding: gpipe forces k = M whatever k the spec carries
+_SPECS.append((ScheduleSpec(kind="gpipe", k=2), ScheduleSpec("kfkb", _M, 1, _W0, 1, _DR)))
+_ROUNDTRIP_IDS.append("gpipe-folds-to-kfkb-kM")
 
 
-@pytest.mark.parametrize(
-    "legacy,spec", _LEGACY_VS_SPEC,
-    ids=[s.kind + f"-k{s.k}" for _, s in _LEGACY_VS_SPEC],
-)
-def test_make_plan_legacy_kwargs_equal_spec(legacy, spec):
-    """Same coordinates, either calling convention -> the SAME lowered
-    plan, bit for bit (grid + exact edge list)."""
-    S, M = 4, 8
-    old = make_plan(S, M, **legacy)
-    new = make_plan(S, M, spec=spec)
-    assert _digest(old) == _digest(new)
-    assert old.name == new.name
-    assert old.spec == new.spec
+@pytest.mark.parametrize("spec,normalized", _SPECS, ids=_ROUNDTRIP_IDS)
+def test_plan_spec_roundtrip(spec, normalized):
+    """plan.spec is normalized (aliases folded, w and zb_policy vectors)
+    and rebuilding from it reproduces the plan, bit for bit (grid + exact
+    edge list) and by name."""
+    plan = make_plan(_S, _M, spec=spec)
+    assert plan.spec == normalized
+    again = make_plan(_S, _M, spec=plan.spec)
+    assert _digest(again) == _digest(plan)
+    assert again.name == plan.name
+    assert again.spec == plan.spec
 
 
 def test_make_plan_rejects_mixing_spec_and_kwargs():
     with pytest.raises(ValueError, match="not both"):
         make_plan(4, 8, 2, spec=ScheduleSpec(kind="zb_h1"))
+    with pytest.raises(ValueError, match="not both"):
+        make_plan(4, 8, micro_batch_size=2, spec=ScheduleSpec(kind="zb_h1"))
 
 
-def test_plan_spec_roundtrip():
-    """plan.spec is normalized (aliases folded, w and zb_policy vectors)
-    and rebuilding from it reproduces the plan."""
-    plan = make_plan(4, 8, 1, kind="gpipe")
-    assert plan.spec == ScheduleSpec(
-        kind="kfkb", k=8, extra_warmup=(0,) * 4, zb_policy=("double_remat",) * 4
-    )
-    again = make_plan(4, 8, spec=plan.spec)
-    assert _digest(plan) == _digest(again)
+#: the (S, M, k, b) the benchmark's set-up builds: the one-stage training
+#: cell (1 stage, 8 micro-batches of 1, 1F1B) and the chat serving cell
+#: (4 stages, 4 groups of 8 slots, 2F2B)
+_PAPER_FORMS = [(1, 8, 1, 1), (4, 4, 2, 8)]
 
 
-def test_enumerate_candidates_legacy_kwargs_equal_search_space():
-    """The legacy axis kwargs and an explicit SearchSpace produce the same
-    candidate list: same order, same coordinates, same lowered digests,
-    same memory pricing."""
+@pytest.mark.parametrize(
+    "S,M,k,b", _PAPER_FORMS, ids=[f"S{S}-M{M}-k{k}-b{b}" for S, M, k, b in _PAPER_FORMS]
+)
+def test_make_plan_paper_form_equals_kfkb_spec(S, M, k, b):
+    """The paper's positional ``(S, M, k, micro_batch_size=b)`` form is
+    shorthand for the kfkb ``spec=``: the same plan bit for bit, the same
+    name and coordinates, and neither call warns."""
+    with _warnings.catch_warnings():
+        _warnings.simplefilter("error")
+        paper = make_plan(S, M, k, micro_batch_size=b)
+        spec = make_plan(S, M, spec=ScheduleSpec(kind="kfkb", k=k, micro_batch_size=b))
+    assert _digest(paper) == _digest(spec)
+    assert paper.name == spec.name == f"{k}F{k}B(b={b})"
+    assert paper.spec == spec.spec
+
+
+def test_enumerate_candidates_default_space_is_the_papers():
+    """No ``space=`` is ``SearchSpace(kinds=("kfkb",))``: the same
+    candidates in the same order, with the same names, coordinates,
+    lowered digests and memory pricing."""
     S, B = 4, 32
     mm = _mm(S)
-    kinds = ("kfkb", "zb_h1", "zb_h2", "interleaved", "interleaved_zb", "zbv")
-    old = enumerate_candidates(
-        S, B, mm, 1e8, max_k=4, kinds=kinds, virtual_degrees=(2,),
-        max_extra_warmup=3,
-    )
-    new = enumerate_candidates(
-        S, B, mm, 1e8,
-        space=SearchSpace(
-            kinds=kinds, virtual_degrees=(2,), max_k=4, max_extra_warmup=3
-        ),
-    )
-    assert [c.name for c in old] == [c.name for c in new]
-    assert [c.spec for c in old] == [c.spec for c in new]
-    assert [c.est_peak_bytes for c in old] == [c.est_peak_bytes for c in new]
-    assert [_digest(c.plan) for c in old] == [_digest(c.plan) for c in new]
-    assert any(c.kind == "zbv" for c in new)  # the registry-only member searches
+    for limit in (1e8, 1e9):
+        default = enumerate_candidates(S, B, mm, limit)
+        papers = enumerate_candidates(S, B, mm, limit, space=SearchSpace(kinds=("kfkb",)))
+        assert default, limit
+        assert [c.name for c in default] == [c.name for c in papers]
+        assert [c.spec for c in default] == [c.spec for c in papers]
+        assert [c.est_peak_bytes for c in default] == [c.est_peak_bytes for c in papers]
+        assert [_digest(c.plan) for c in default] == [_digest(c.plan) for c in papers]
+        assert {c.kind for c in default} == {"kfkb"}
 
 
 def test_candidate_record_cache_share_one_spec_currency():
@@ -163,8 +167,9 @@ def test_candidate_record_cache_share_one_spec_currency():
 
     S, B = 4, 32
     cands = enumerate_candidates(
-        S, B, _mm(S), 1e8, max_k=2, kinds=("kfkb", "zb_h1", "zbv"),
+        S, B, _mm(S), 1e8, space=SearchSpace(kinds=("kfkb", "zb_h1", "zbv"), max_k=2),
     )
+    assert any(c.kind == "zbv" for c in cands)  # the registry-only member searches
     costs_for = lambda c: StageCosts.uniform(S, 0.1, act_bytes=1.0)  # noqa: E731
     net = uniform_network(S, lambda: StableTrace(100.0))
     tuner = AutoTuner(cands, costs_for, NetworkProfiler(net))
@@ -173,49 +178,6 @@ def test_candidate_record_cache_share_one_spec_currency():
     assert rec.chosen_spec == winner.spec == winner.plan.spec
     key = CompiledStepCache.plan_key(winner.table)
     assert winner.spec in key
-
-
-# ---------------------------------------------------------------------------
-# Deprecation contract (PR 6): legacy forms warn, modern forms are silent
-# ---------------------------------------------------------------------------
-
-
-def test_make_plan_legacy_kind_kwargs_warn():
-    """The kind/num_virtual/extra_warmup kwargs emit DeprecationWarning
-    pointing at spec=ScheduleSpec(...); the paper's original positional
-    (S, M, k, micro_batch_size=b) form and the spec= form stay silent."""
-    with pytest.warns(DeprecationWarning, match="spec=ScheduleSpec"):
-        make_plan(4, 8, 1, kind="zb_h1")
-    with pytest.warns(DeprecationWarning, match="deprecated"):
-        make_plan(4, 8, 2, kind="interleaved", num_virtual=2)
-    with pytest.warns(DeprecationWarning, match="deprecated"):
-        make_plan(4, 8, 1, extra_warmup=1, kind="zb_h2")
-    with _warnings.catch_warnings():
-        _warnings.simplefilter("error")
-        make_plan(4, 8, 2, micro_batch_size=2)  # paper form: not deprecated
-        make_plan(4, 8, spec=ScheduleSpec(kind="zbv", k=2))
-
-
-def test_enumerate_candidates_legacy_axis_kwargs_warn():
-    """Each legacy axis kwarg triggers the warning (which names the kwargs
-    given); space= and the bare 4-positional call stay silent."""
-    mm = _mm(4)
-    with pytest.warns(DeprecationWarning, match=r"max_k=.*space=SearchSpace"):
-        enumerate_candidates(4, 16, mm, 1e9, max_k=1)
-    with pytest.warns(DeprecationWarning, match="kinds="):
-        enumerate_candidates(4, 16, mm, 1e9, kinds=("kfkb",))
-    with _warnings.catch_warnings():
-        _warnings.simplefilter("error")
-        enumerate_candidates(4, 16, mm, 1e9)
-        enumerate_candidates(4, 16, mm, 1e9, space=SearchSpace(max_k=1))
-
-
-def test_enumerate_candidates_rejects_space_plus_legacy_axes():
-    with pytest.warns(DeprecationWarning):
-        with pytest.raises(ValueError, match="not both"):
-            enumerate_candidates(
-                4, 16, _mm(4), 1e9, max_k=1, space=SearchSpace(max_k=1)
-            )
 
 
 # ---------------------------------------------------------------------------
@@ -229,7 +191,6 @@ def test_unknown_kind_fails_closed_everywhere():
     search; it is never silently skipped."""
     for call in (
         lambda: get_kind("zb_h3"),
-        lambda: make_plan(4, 8, 1, kind="zb_h3"),
         lambda: make_plan(4, 8, spec=ScheduleSpec(kind="zb_h3")),
     ):
         with pytest.raises(ValueError, match="registered kinds") as ei:
@@ -237,18 +198,18 @@ def test_unknown_kind_fails_closed_everywhere():
         for kind in registered_kinds():
             assert kind in str(ei.value)
     with pytest.raises(ValueError, match="unknown schedule kind"):
-        enumerate_candidates(4, 32, _mm(4), 1e8, kinds=("kfkb", "zb_h3"))
+        enumerate_candidates(4, 32, _mm(4), 1e8, space=SearchSpace(kinds=("kfkb", "zb_h3")))
 
 
 def test_known_kinds_covers_registry_and_aliases():
     """The candidate search accepts exactly the registry + aliases — a
-    registered kind can never be rejected as unknown (the old hardcoded
-    ``PLAN_KINDS + ("1f1b", "gpipe")`` tuple drifted by construction)."""
+    registered kind can never be rejected as unknown (a hardcoded tuple of
+    kinds plus aliases would drift by construction)."""
     ks = known_kinds()
     assert set(registered_kinds()) <= set(ks)
     assert {"1f1b", "gpipe"} <= set(ks)
     # every known name is accepted by the search (smoke: no ValueError)
-    enumerate_candidates(4, 16, _mm(4), 1e9, max_k=1, kinds=ks)
+    enumerate_candidates(4, 16, _mm(4), 1e9, space=SearchSpace(kinds=ks, max_k=1))
 
 
 def test_duplicate_registration_rejected():
@@ -269,10 +230,10 @@ def test_capability_flags_gate_coordinates():
     virtual-capable kinds, ZB-V pinned to 2 chunks, warmup only on
     warmup-capable kinds."""
     with pytest.raises(ValueError, match="interleaved kind"):
-        make_plan(4, 8, 1, kind="zb_h1", num_virtual=2)
+        make_plan(4, 8, spec=ScheduleSpec(kind="zb_h1", num_virtual=2))
     with pytest.raises(ValueError, match="exactly 2 chunks"):
-        make_plan(4, 8, 1, kind="zbv", num_virtual=3)
-    assert make_plan(4, 8, 1, kind="zbv").num_virtual == 2  # coerced default
+        make_plan(4, 8, spec=ScheduleSpec(kind="zbv", num_virtual=3))
+    assert make_plan(4, 8, spec=ScheduleSpec(kind="zbv")).num_virtual == 2  # coerced default
 
 
 # ---------------------------------------------------------------------------
@@ -281,17 +242,14 @@ def test_capability_flags_gate_coordinates():
 
 _ALLOWED = {os.path.join("core", "kinds.py"), os.path.join("core", "schedule.py")}
 #: schedule-kind string dispatch (`plan.kind == "zb_h2"`-style ladders) or
-#: membership tests against the legacy kind-set tuples
+#: membership tests against a literal tuple or an upper-case kind-set constant
 _DISPATCH = [
     re.compile(
         r"kind\s*(?:==|!=)\s*[\"']"
         r"(?:kfkb|zb_h1|zb_h2|interleaved|interleaved_zb|zbv|1f1b|gpipe)[\"']"
     ),
     re.compile(r"kind\s+(?:not\s+)?in\s+\("),
-    re.compile(
-        r"kind\s+(?:not\s+)?in\s+"
-        r"(?:PLAN_KINDS|ZB_KINDS|INTERLEAVED_KINDS|WARMUP_KINDS)"
-    ),
+    re.compile(r"kind\s+(?:not\s+)?in\s+[A-Z_]*KINDS\b"),
 ]
 
 
@@ -319,67 +277,6 @@ def test_no_kind_string_dispatch_outside_registry():
     )
 
 
-#: deprecated kwarg sets per callee — a call site naming any of these is a
-#: legacy form (AST-matched, so formatting/line-breaks can't hide one)
-_LEGACY_FORMS = {
-    "make_plan": {"kind", "num_virtual", "extra_warmup"},
-    "enumerate_candidates": {
-        "kinds", "virtual_degrees", "max_k", "min_microbatches", "max_extra_warmup"
-    },
-    "Coordinator": {"telemetry", "on_iteration"},
-}
-_REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-_ROOTS = [
-    _SRC,
-    os.path.join(os.path.dirname(__file__)),
-    os.path.join(os.path.dirname(__file__), "..", "benchmarks"),
-    os.path.join(os.path.dirname(__file__), "..", "examples"),
-]
-_LEGACY_EXEMPT = {os.path.abspath(__file__)}  # this suite calls them on purpose
-
-
-def test_no_legacy_call_forms_outside_conformance_suite():
-    """PR 6's migration lock: every in-repo caller of make_plan /
-    enumerate_candidates / Coordinator uses the ScheduleSpec / SearchSpace
-    / typed-hook forms.  The deprecated kwargs may appear only in this
-    conformance suite.  AST-based so a reformatted call can't slip past
-    the CI grep (which runs a coarser single-line scan of the same names
-    for log visibility)."""
-    offenders = []
-    for base in _ROOTS:
-        for root, _, files in os.walk(os.path.abspath(base)):
-            for f in sorted(files):
-                if not f.endswith(".py"):
-                    continue
-                path = os.path.join(root, f)
-                if os.path.abspath(path) in _LEGACY_EXEMPT:
-                    continue
-                with open(path) as fh:
-                    tree = ast.parse(fh.read())
-                for node in ast.walk(tree):
-                    if not isinstance(node, ast.Call):
-                        continue
-                    name = (
-                        node.func.id if isinstance(node.func, ast.Name)
-                        else node.func.attr if isinstance(node.func, ast.Attribute)
-                        else None
-                    )
-                    banned = _LEGACY_FORMS.get(name)
-                    if not banned:
-                        continue
-                    hit = banned & {kw.arg for kw in node.keywords if kw.arg}
-                    if hit:
-                        offenders.append(
-                            f"{os.path.relpath(path, _REPO)}:{node.lineno}: "
-                            f"{name}({', '.join(sorted(hit))}=...)"
-                        )
-    assert not offenders, (
-        "deprecated legacy call forms outside tests/test_spec_api.py "
-        "(use spec=ScheduleSpec / space=SearchSpace / hooks= / "
-        "telemetry_sink=):\n" + "\n".join(offenders)
-    )
-
-
 # ---------------------------------------------------------------------------
 # ZB-V: the registry-only member's acceptance gates
 # ---------------------------------------------------------------------------
@@ -393,8 +290,8 @@ def test_zbv_peak_live_below_plain_interleaved():
     from repro.core import predicted_peak_live
 
     for S, M, k in ((4, 16, 1), (4, 32, 2), (8, 32, 1), (3, 12, 1)):
-        zbv = make_plan(S, M, k, kind="zbv")
-        il = make_plan(S, M, k, kind="interleaved", num_virtual=2)
+        zbv = make_plan(S, M, spec=ScheduleSpec(kind="zbv", k=k))
+        il = make_plan(S, M, spec=ScheduleSpec(kind="interleaved", k=k, num_virtual=2))
         assert max(peak_live_activations(zbv)) < max(peak_live_activations(il))
         assert all(
             p <= pr
@@ -417,7 +314,7 @@ def test_zbv_makespan_no_worse_than_1f1b_under_preemption():
             make_plan(S, M, 1), costs, uniform_network(S, trace)
         ).pipeline_length
         len_zbv = simulate_plan(
-            make_plan(S, M, 1, kind="zbv"), costs, uniform_network(S, trace)
+            make_plan(S, M, spec=ScheduleSpec(kind="zbv")), costs, uniform_network(S, trace)
         ).pipeline_length
         assert len_zbv <= len_1f1b * 1.001, (S, M, len_zbv, len_1f1b)
 
@@ -426,7 +323,7 @@ def test_zbv_lowered_plan_is_near_zero_bubble():
     """Unit-cost bubble fraction of the lowered V stays single-digit —
     the 2S-slot cap actually buys the zero-bubble operating point."""
     for S, M in ((4, 16), (8, 32)):
-        stats = make_plan(S, M, 1, kind="zbv").lower().stats()
+        stats = make_plan(S, M, spec=ScheduleSpec(kind="zbv")).lower().stats()
         assert stats["bubble_fraction"] < 0.10, (S, M, stats)
 
 
@@ -436,7 +333,7 @@ def test_zbv_weight_placement_refinable():
     price on the V placement."""
     from repro.core import optimize_weight_placement
 
-    plan = make_plan(4, 8, 1, kind="zbv")
+    plan = make_plan(4, 8, spec=ScheduleSpec(kind="zbv"))
     assert get_kind("zbv").weight_placement_refinable
     skew = StageCosts(
         fwd_time=[1.0, 0.8, 1.2, 0.9], bwd_time=[3.0, 2.0, 2.4, 2.8],

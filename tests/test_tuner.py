@@ -58,7 +58,7 @@ def test_tuner_prefers_larger_k_when_network_slow():
     slow = uniform_network(4, lambda: StableTrace(1.0))
     tuner = AutoTuner(cands, costs_for, NetworkProfiler(slow))
     rec = tuner.tune(now=0.0)
-    assert rec.chosen_k > 1
+    assert rec.chosen_spec.k > 1
 
 
 def test_tuner_tracks_regime_change():
@@ -79,7 +79,7 @@ def test_tuner_tracks_regime_change():
     # regime's estimates must be strictly worse than the exclusive one's
     # (the paper notes improvement is NOT monotone in k, so we do not
     # assert k ordering between regimes — only that tuning tracks them)
-    assert recs[0].chosen_k > 1 and recs[2].chosen_k > 1
+    assert recs[0].chosen_spec.k > 1 and recs[2].chosen_spec.k > 1
     assert min(recs[2].estimates.values()) > min(recs[1].estimates.values())
 
 
@@ -90,7 +90,7 @@ def test_coordinator_switches_and_improves():
     coord = Coordinator(tuner, net, global_batch=32, tuning_interval=1e9)
     summary = coord.run(5)
     assert len(summary.iterations) == 5
-    assert summary.tuning and summary.tuning[0].chosen_k > 1
+    assert summary.tuning and summary.tuning[0].chosen_spec.k > 1
     # compare against never tuning (fixed 1F1B)
     fixed = simulate_plan(cands[0].plan, costs_for(cands[0]), net).pipeline_length
     assert summary.iterations[0].length <= fixed
@@ -136,13 +136,13 @@ def test_tuner_selects_schedule_kind_not_just_k():
 
     fast = uniform_network(S, lambda: StableTrace(1e12))
     rec = AutoTuner(cands, costs_for, NetworkProfiler(fast)).tune(0.0)
-    assert rec.chosen_kind in ("zb_h1", "interleaved")  # beats every kFkB plan
+    assert rec.chosen_spec.kind in ("zb_h1", "interleaved")  # beats every kFkB plan
     assert rec.estimates[rec.chosen] == min(rec.estimates.values())
 
     slow = uniform_network(S, lambda: StableTrace(0.5))
     rec2 = AutoTuner(cands, costs_for, NetworkProfiler(slow)).tune(0.0)
     assert rec2.estimates[rec2.chosen] == min(rec2.estimates.values())
-    assert rec2.chosen_kind in ("kfkb", "zb_h1", "interleaved")
+    assert rec2.chosen_spec.kind in ("kfkb", "zb_h1", "interleaved")
 
 
 def _mm(S=4):
@@ -193,9 +193,9 @@ def test_tuner_selects_zb_h2_when_memory_admits_extra_warmup():
     assert max(h2.extra_warmup) >= 1 and h2.est_peak_bytes <= 1e8
 
     rec = AutoTuner(cands, _uniform_costs_for(S), NetworkProfiler(_preempted_network(S))).tune(0.0)
-    assert rec.chosen_kind == "zb_h2"
-    assert rec.chosen_extra_warmup == h2.extra_warmup
-    assert max(rec.chosen_extra_warmup) >= 1
+    assert rec.chosen_spec.kind == "zb_h2"
+    assert rec.chosen_spec.extra_warmup == h2.extra_warmup
+    assert max(rec.chosen_spec.extra_warmup) >= 1
     assert rec.estimates[rec.chosen] == min(rec.estimates.values())
 
 
@@ -222,8 +222,8 @@ def test_tuner_refuses_zb_h2_when_memory_forbids_it():
     assert [c.kind for c in cands] == ["zb_h1"]  # H2 refused entirely
 
     rec = AutoTuner(cands, _uniform_costs_for(S), NetworkProfiler(_preempted_network(S))).tune(0.0)
-    assert rec.chosen_kind == "zb_h1"
-    assert max(rec.chosen_extra_warmup) == 0
+    assert rec.chosen_spec.kind == "zb_h1"
+    assert max(rec.chosen_spec.extra_warmup) == 0
 
 
 def test_vector_warmup_beats_every_scalar_on_memory_skewed_pipeline():
@@ -283,7 +283,7 @@ def test_vector_warmup_beats_every_scalar_on_memory_skewed_pipeline():
     )
     rec = tuner.tune(0.0)
     assert rec.chosen == h2.name
-    assert rec.chosen_extra_warmup == target
+    assert rec.chosen_spec.extra_warmup == target
 
 
 def test_tuner_lowers_each_candidate_at_most_once():
@@ -352,9 +352,9 @@ def test_tuner_selects_saved_residual_on_admitting_stages():
     rec = tuner.tune(0.0)
     assert rec.estimates[rec.chosen] == min(rec.estimates.values())
     assert "+SR" in rec.chosen
-    assert rec.chosen_zb_policy in mixed
-    assert rec.chosen_zb_policy[0] == "double_remat"  # the tight stage
-    assert rec.chosen_zb_policy[1:] == ("saved_residual",) * (S - 1)
+    assert rec.chosen_spec.zb_policy in mixed
+    assert rec.chosen_spec.zb_policy[0] == "double_remat"  # the tight stage
+    assert rec.chosen_spec.zb_policy[1:] == ("saved_residual",) * (S - 1)
     # and the SR pick genuinely beats the DR baseline's estimate
     dr_name = by_policy[("double_remat",) * S].name
     assert rec.estimates[rec.chosen] < rec.estimates[dr_name]
